@@ -1,8 +1,8 @@
 """Jit-able public wrapper for the flash-attention kernel.
 
 Handles layout ((B, S, H, D) model layout -> (B*H, S, D) kernel layout),
-GQA head mapping, and padding to block multiples.  ``interpret=True``
-validates the kernel on CPU.
+GQA head mapping, and padding to block multiples.  ``interpret`` runs the
+kernel through the Pallas interpreter, which the CPU tests use.
 """
 from __future__ import annotations
 

@@ -13,22 +13,29 @@ DMA'd HBM->VMEM exactly like contiguous KV blocks — gather by DMA
 descriptor, never materialized as a contiguous copy (the pure-jnp
 reference in ``ref.py`` pays that copy; the kernel does not).
 
-Grid: ``(B, KV_heads, n_blocks)`` with the block axis innermost and
-sequential, carrying online-softmax state (m, l, acc) in VMEM scratch
-across block iterations — the same recipe as ``kernel.py``'s flash
-forward.  Unallocated table entries must point at a *valid* page index
-(the pool uses page 0); their keys land beyond ``pos`` and are masked.
+Grid: ``(B, n_blocks)`` with the block axis innermost and sequential,
+carrying online-softmax state (m, l, acc) in VMEM scratch across block
+iterations — the same recipe as ``kernel.py``'s flash forward.  Unallocated
+table entries must point at a *valid* page index (the pool uses page 0);
+their keys land beyond ``pos`` and are masked.
 
-Tiling note: the per-program MXU shapes are (G x D) @ (D x page) — small
-for GQA groups; correctness-first (validated in interpret mode on CPU via
-``tests``), production tiling would fold slots into the sublane dim.
+Tiling: one program reads a whole page, every KV head of it — the block
+``(1, page, KV, D)`` spans the pool's two minor dims, as the TPU tiling
+asks (a one-head block would put a 1 in the second-minor dim).  The page
+is flattened to ``(page*KV, D)`` rows and all ``H`` query heads score
+against every row in one ``(H, D) @ (D, page*KV)`` product; a static
+column map masks the rows of other KV heads.  That spends KV times the
+necessary MXU work on a memory-bound step, to keep every operand tile
+aligned.
 
 **Quantized pools.**  With ``k_scales``/``v_scales`` (P, KV) float32 the
 pools hold int8/fp8 values; the scales ride in as two extra VMEM side
-inputs whose BlockSpec index map is the *same* ``tbl[b, j]`` lookup as
-the page DMA, so each program sees exactly its page's (1, 1) scale.  K/V
-are dequantized in-register right after the VMEM load — HBM moves the
-quantized bytes, and no fp copy of the pool is ever materialized.
+inputs, laid out ``(P, 1, KV)`` so a page's row is a whole tile, whose
+BlockSpec index map is the *same* ``tbl[b, j]`` lookup as the page DMA.
+Only a query head's own KV head survives the column mask, so each score
+row and each output row is dequantized by that head's scale, expanded
+in-register from the page's ``(1, KV)`` row.  HBM moves the quantized
+bytes, and no fp copy of the pool is ever materialized.
 """
 from __future__ import annotations
 
@@ -36,22 +43,23 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, page_size: int, window: int,
+def _paged_decode_kernel(tables_ref, pos_ref, col_ref, q_ref, k_ref, v_ref,
+                         *rest, scale: float, page_size: int, window: int,
                          softcap: float, quantized: bool):
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        head_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -59,29 +67,30 @@ def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    if quantized:
-        # fused dequant: one (page, KV-head) scale per program, indexed
-        # by the same tbl[b, j] map that steered the page DMA
-        k = k * ks_ref[0, 0]
-        v = v * vs_ref[0, 0]
+    _, page, KV, D = k_ref.shape
+    q = q_ref[0].astype(jnp.float32) * scale             # (H, D)
+    k = k_ref[0].astype(jnp.float32).reshape(page * KV, D)
+    v = v_ref[0].astype(jnp.float32).reshape(page * KV, D)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, page)
+                            preferred_element_type=jnp.float32)  # (H, page*KV)
+    if quantized:
+        # a row keeps only its own KV head's columns, so one scale per
+        # row dequantizes them: (H, KV) head map x the page's (1, KV) row
+        head = head_ref[...]
+        s = s * jnp.sum(head * ks_ref[0], axis=1, keepdims=True)
     if softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
 
     pos = pos_ref[b]
-    k_pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)                            # (G, page)
-    valid = k_pos <= pos
+    col = col_ref[...]               # token offset in page; -1: other head
+    k_pos = j * page_size + col
+    valid = (col >= 0) & (k_pos <= pos)
     if window > 0:
         valid &= k_pos > (pos - window)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]                                   # (G, 1)
+    m_prev = m_scr[...]                                   # (H, 1)
     m_cur = jnp.max(s, axis=1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     m_safe = jnp.where(m_new <= NEG_INF, 0.0, m_new)
@@ -89,14 +98,16 @@ def _paged_decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     corr = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - m_safe))
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32)  # (H, D)
+    if quantized:
+        pv = pv * jnp.sum(head * vs_ref[0], axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * corr + pv
     m_scr[...] = m_new
 
     @pl.when(j == nb - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_flash_decode(q, k_pages, v_pages, block_tables, pos, *,
@@ -117,45 +128,51 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, pos, *,
     P, page, KV, _ = k_pages.shape
     G = H // KV
     nb = block_tables.shape[1]
-    qr = q.reshape(B, KV, G, D)
-    scale = D ** -0.5
     quantized = k_scales is not None
 
+    # static maps from score row r (query head r, KV head r // G) and
+    # flattened page row c (token c // KV, KV head c % KV)
+    row_head = np.arange(H)[:, None] // G
+    c = np.arange(page * KV)[None, :]
+    col = np.where(c % KV == row_head, c // KV, -1).astype(np.int32)
+
     kernel = functools.partial(
-        _paged_decode_kernel, scale=scale, page_size=page, window=window,
-        softcap=softcap, quantized=quantized)
-    page_spec = pl.BlockSpec((1, page, 1, D),
-                             lambda b, h, j, tbl, ps: (tbl[b, j], 0, h, 0))
+        _paged_decode_kernel, scale=D ** -0.5, page_size=page,
+        window=window, softcap=softcap, quantized=quantized)
+    page_spec = pl.BlockSpec((1, page, KV, D),
+                             lambda b, j, tbl, ps: (tbl[b, j], 0, 0, 0))
+    whole = lambda shape: pl.BlockSpec(shape, lambda b, j, tbl, ps: (0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda b, h, j, tbl, ps: (b, h, 0, 0)),
+        whole((H, page * KV)),
+        pl.BlockSpec((1, H, D), lambda b, j, tbl, ps: (b, 0, 0)),
         page_spec,
         page_spec,
     ]
-    operands = [qr, k_pages, v_pages]
+    operands = [jnp.asarray(col), q.reshape(B, H, D), k_pages, v_pages]
     if quantized:
         # the scale side inputs reuse the page DMA's tbl[b, j] steering
-        scale_spec = pl.BlockSpec((1, 1),
-                                  lambda b, h, j, tbl, ps: (tbl[b, j], h))
-        in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scales, jnp.float32),
-                     jnp.asarray(v_scales, jnp.float32)]
+        scale_spec = pl.BlockSpec((1, 1, KV),
+                                  lambda b, j, tbl, ps: (tbl[b, j], 0, 0))
+        head = (np.arange(KV)[None, :] == row_head).astype(np.float32)
+        in_specs += [whole((H, KV)), scale_spec, scale_spec]
+        operands += [jnp.asarray(head),
+                     jnp.asarray(k_scales, jnp.float32).reshape(P, 1, KV),
+                     jnp.asarray(v_scales, jnp.float32).reshape(P, 1, KV)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, nb),
+        grid=(B, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, j, tbl, ps: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, j, tbl, ps: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # running max
-            pltpu.VMEM((G, 1), jnp.float32),    # running sum
-            pltpu.VMEM((G, D), jnp.float32),    # accumulator
+            pltpu.VMEM((H, 1), jnp.float32),    # running max
+            pltpu.VMEM((H, 1), jnp.float32),    # running sum
+            pltpu.VMEM((H, D), jnp.float32),    # accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(pos, jnp.int32),
       *operands)

@@ -17,6 +17,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
+from jax.sharding import AxisType
 
 
 @dataclass(frozen=True)
@@ -56,9 +57,11 @@ class MeshSpec:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over host devices for tests/examples."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

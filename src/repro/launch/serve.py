@@ -20,6 +20,7 @@ from ..dvfs import DvfsSession
 from ..models import build_model
 from ..obs import Tracer
 from ..serve import Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -37,6 +38,7 @@ def main():
                     help="record a Chrome/Perfetto-loadable telemetry "
                          "trace (repro.obs schema) of the run here")
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = Tracer(meta={"launcher": "serve", "arch": args.arch,
                           "chip": args.chip,
                           "governor": args.governor}) \

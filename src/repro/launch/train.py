@@ -4,9 +4,11 @@ Production entry point: builds the model from the registry, plans the
 per-phase DVFS schedule through a :class:`~repro.dvfs.DvfsSession` with
 the chosen governor, and drives the fault-tolerant trainer with the
 session's executor actuating (and metering) the plan around every step.
-On this CPU container the full configs are not executable — ``--smoke``
-runs the reduced config end-to-end; the full config path is exactly what
-a TPU deployment would run.
+``--smoke`` runs the reduced config, small enough for a CPU.  A full
+config needs the accelerator memory its parameters and AdamW state take
+(16 bytes per parameter): on one 16 GB TPU v5e that is about 0.5B
+parameters, so gpt3-xl at full depth trains on a sharded mesh
+(``chip_smoke.py --chips 4`` runs that path).
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ from ..models import build_model
 from ..obs import Tracer
 from ..train import OptimizerConfig, make_train_step
 from ..train.loop import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -52,6 +55,7 @@ def main():
                     help="record a Chrome/Perfetto-loadable telemetry "
                          "trace (repro.obs schema) of the run here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     shape = get_shape(args.shape)

@@ -13,7 +13,6 @@ never drift apart: they are generated by the same declaration code.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -74,47 +73,19 @@ def cast(x, dtype):
 
 
 def _ambient_mesh():
-    """The mesh the current trace runs under, or None.
-
-    Version-compat: prefers the abstract mesh (``jax.set_mesh`` /
-    ``use_mesh``, JAX >= 0.5); falls back to the 0.4.x thread-local
-    physical mesh set by the ``with mesh:`` context manager.
-    """
-    import jax.sharding as jsh
-    try:
-        am = jsh.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return am
-    except AttributeError:
-        pass
-    except Exception:
-        return None
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The abstract mesh the current trace runs under (``jax.set_mesh``);
+    empty when no mesh is set."""
+    return jax.sharding.get_abstract_mesh()
 
 
-def shard_hint(x, *axes):
-    """Best-effort activation sharding constraint on the ambient mesh.
-
-    ``axes`` entries per dim: None | mesh-axis name | tuple of names |
-    "batch" (resolves to the present data axes ("pod","data")).  Names not
-    in the mesh or non-divisible dims are dropped, so the same model code
-    runs un-meshed (smoke tests) and on any production mesh.
-    """
+def _hint_spec(am, shape, axes):
+    """PartitionSpec for ``shape`` on mesh ``am`` from :func:`shard_hint`'s
+    per-dim ``axes`` entries."""
     from jax.sharding import PartitionSpec
-    am = _ambient_mesh()
-    if am is None:
-        return x
     names = set(am.axis_names)
     sizes = dict(am.shape)
     spec = []
-    for dim, a in zip(x.shape, axes):
+    for dim, a in zip(shape, axes):
         if a is None:
             spec.append(None)
             continue
@@ -131,7 +102,21 @@ def shard_hint(x, *axes):
             spec.append(cand if len(cand) > 1 else cand[0])
         else:
             spec.append(None)
-    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
+    return PartitionSpec(*spec)
+
+
+def shard_hint(x, *axes):
+    """Best-effort activation sharding constraint on the ambient mesh.
+
+    ``axes`` entries per dim: None | mesh-axis name | tuple of names |
+    "batch" (resolves to the present data axes ("pod","data")).  Names not
+    in the mesh or non-divisible dims are dropped, so the same model code
+    runs un-meshed (smoke tests) and on any production mesh.
+    """
+    am = _ambient_mesh()
+    if am.empty:
+        return x
+    return jax.lax.with_sharding_constraint(x, _hint_spec(am, x.shape, axes))
 
 
 def cast_sharded(w, dtype, *axes):
@@ -144,7 +129,7 @@ def cast_sharded(w, dtype, *axes):
 def _heads_shardable(n_heads: int) -> bool:
     """Whether the head dim divides the ambient model axis."""
     am = _ambient_mesh()
-    if am is None or "model" not in am.axis_names:
+    if am.empty or "model" not in am.axis_names:
         return True
     return n_heads % dict(am.shape)["model"] == 0
 
@@ -353,11 +338,28 @@ def init_attention(b: ParamBuilder, d_in: int, n_heads: int, n_kv: int,
     }
 
 
-# Pallas kernel dispatch for model attention:
-#   "off"       — pure-jnp chunked attention (default on CPU),
-#   "interpret" — Pallas flash kernels in interpret mode (CPU-validatable),
-#   "tpu"       — compiled Pallas kernels (real TPU runs).
-PALLAS_MODE = os.environ.get("REPRO_USE_PALLAS", "off")
+def _attention_kernels() -> Optional[Dict[str, bool]]:
+    """Keyword arguments for the Pallas attention kernels, or None for the
+    ``jnp`` path.  The compiled kernels run on a TPU backend; every other
+    platform takes the ``jnp`` path.  Read while tracing, never at import,
+    so the choice follows the backend the program is traced for."""
+    return {"interpret": False} if jax.default_backend() == "tpu" else None
+
+
+def _per_device(kernel, q, k, v):
+    """Run a Pallas attention kernel on each device's share of q, k, v
+    (B, S, heads, D) under the ambient mesh: batch rows over the data axes,
+    heads over the model axis when the KV heads divide it.  The compiler
+    does not partition a Mosaic kernel by itself."""
+    am = _ambient_mesh()
+    if am.empty:
+        return kernel(q, k, v)
+    heads = "model" if k.shape[2] % dict(am.shape).get("model", 1) == 0 \
+        else None
+    q_spec = _hint_spec(am, q.shape, ("batch", None, heads, None))
+    kv_spec = _hint_spec(am, k.shape, ("batch", None, heads, None))
+    return jax.shard_map(kernel, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
 
 
 def attention_block(p: Params, x: jnp.ndarray, *, cfg_theta: float,
@@ -385,15 +387,13 @@ def attention_block(p: Params, x: jnp.ndarray, *, cfg_theta: float,
         if kv_x is None:
             k = apply_rope(k, q_offset + jnp.arange(kv_src.shape[1]),
                            cfg_theta)
-    use_pallas = (PALLAS_MODE != "off" and softcap == 0.0
-                  and q_offset == 0 and kv_x is None
-                  and kv_valid_len is None)
-    if use_pallas:
+    kernels = _attention_kernels()
+    if (kernels is not None and softcap == 0.0 and q_offset == 0
+            and kv_x is None and kv_valid_len is None):
         from ..kernels.flash_attention import flash_attention_train
-        o = flash_attention_train(q, k, v, causal, window,
-                                  min(block_k, q.shape[1]),
-                                  min(block_k, k.shape[1]),
-                                  PALLAS_MODE == "interpret")
+        o = _per_device(lambda q, k, v: flash_attention_train(
+            q, k, v, causal, window, min(block_k, q.shape[1]),
+            min(block_k, k.shape[1]), kernels["interpret"]), q, k, v)
     else:
         o = chunked_attention(q, k, v, causal=causal and kv_x is None,
                               window=window, q_offset=q_offset,
@@ -672,20 +672,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, *,
     """Single-token attention against a paged cache.
 
     q: (B, 1, H, D); pools (P, page, KV, D); block_tables (B, nb);
-    pos (B,).  Dispatches to the Pallas page-read kernel under
-    ``REPRO_USE_PALLAS`` (the block table feeds the DMA index map — no
-    materialized gather), else to the gather-based jnp reference.  With
+    pos (B,).  Dispatches to the Pallas page-read kernel on a TPU backend
+    (the block table feeds the DMA index map — no materialized gather),
+    else to the gather-based jnp reference.  With
     ``k_scales``/``v_scales`` (P, KV) the pools hold quantized values
     and K/V are dequantized in-register (fused into the kernel — the
     HBM read stays at the quantized width).
     """
     from ..kernels.flash_attention import (paged_attention_ref,
                                            paged_flash_decode)
-    if PALLAS_MODE != "off":
+    kernels = _attention_kernels()
+    if kernels is not None:
         return paged_flash_decode(q, k_pages, v_pages, block_tables, pos,
                                   window=window, softcap=softcap,
                                   k_scales=k_scales, v_scales=v_scales,
-                                  interpret=PALLAS_MODE == "interpret")
+                                  **kernels)
     return paged_attention_ref(q, k_pages, v_pages, block_tables, pos,
                                window=window, softcap=softcap,
                                k_scales=k_scales, v_scales=v_scales)

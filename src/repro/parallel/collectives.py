@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _bucket_by_dest(xt, flat_e, flat_w, ep: int, experts_per_shard: int,
@@ -59,19 +58,16 @@ def _bucket_by_dest(xt, flat_e, flat_w, ep: int, experts_per_shard: int,
 
 def moe_all_to_all(xt, top_e, top_w, expert_fn: Callable, *,
                    n_experts: int, axis_name: str,
-                   capacity_factor: float = 2.0,
-                   axis_size: int = 0):
+                   capacity_factor: float = 2.0):
     """Run ``expert_fn`` over tokens via an explicit all-to-all exchange.
 
     Must be called inside ``shard_map`` with the token dim sharded over
     ``axis_name`` and the experts owned shard-major.  xt: (T_l, d) local
     tokens; top_e/top_w: (T_l, K) routing.  expert_fn(local_expert_idx,
     x) -> y applies the shard's experts ((n_recv, d) + ids -> (n_recv,
-    d)).  Returns (T_l, d) combined outputs.  ``axis_size`` is the static
-    size of ``axis_name`` (pass it explicitly on JAX versions without
-    ``lax.axis_size``).
+    d)).  Returns (T_l, d) combined outputs.
     """
-    ep = axis_size or lax.axis_size(axis_name)
+    ep = lax.axis_size(axis_name)
     experts_per_shard = n_experts // ep
     T_l, K = top_e.shape
     d = xt.shape[-1]
@@ -118,21 +114,18 @@ def moe_all_to_all_sharded(mesh: Mesh, xt, top_e, top_w, expert_weights,
     """shard_map wrapper: xt (T, d) sharded over ``axis_name``; expert
     weight arrays have leading dim E sharded over ``axis_name``."""
 
-    ep = int(mesh.shape[axis_name])
-
     def body(xt_l, e_l, w_l, *weights_l):
         def expert_fn(local_eid, x):
             return activation_fn(local_eid, x, weights_l)
         return moe_all_to_all(xt_l, e_l, w_l, expert_fn,
                               n_experts=n_experts, axis_name=axis_name,
-                              capacity_factor=capacity_factor,
-                              axis_size=ep)
+                              capacity_factor=capacity_factor)
 
     pspec_tok = P(axis_name)
     pspec_w = P(axis_name)
     flat_w = jax.tree.leaves(expert_weights)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(pspec_tok, pspec_tok, pspec_tok)
-                   + tuple(pspec_w for _ in flat_w),
-                   out_specs=pspec_tok)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(pspec_tok, pspec_tok, pspec_tok)
+                       + tuple(pspec_w for _ in flat_w),
+                       out_specs=pspec_tok)
     return fn(xt, top_e, top_w, *flat_w)

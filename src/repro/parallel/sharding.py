@@ -26,19 +26,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-def use_mesh(mesh: Mesh):
-    """Version-compat ambient-mesh context manager.
-
-    ``jax.sharding.use_mesh`` where available (JAX >= 0.5); on 0.4.x the
-    ``Mesh`` object itself is the context manager that sets the thread-
-    local resource env ``shard_hint`` reads.
-    """
-    import jax.sharding as jsh
-    if hasattr(jsh, "use_mesh"):
-        return jsh.use_mesh(mesh)
-    return mesh
-
-
 LOGICAL_RULES: Dict[str, Tuple[str, ...]] = {
     "heads": ("model",),
     "kv": ("model",),

@@ -61,7 +61,10 @@ class Trainer:
                  failure_injector: Optional[FailureInjector] = None,
                  seed: int = 0):
         self.model = model
-        self.train_step = jax.jit(train_step)
+        # the step consumes its input state: donating it lets the update
+        # reuse the parameter and optimizer buffers instead of holding a
+        # second copy of both on the device
+        self.train_step = jax.jit(train_step, donate_argnums=0)
         self.pipeline = pipeline
         self.ckpt = ckpt
         self.cfg = cfg

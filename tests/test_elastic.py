@@ -21,6 +21,7 @@ def test_save_on_8_restore_on_4(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt import CheckpointManager, reshard_restore
         from repro.configs import REGISTRY, smoke_config
+        from repro.launch.mesh import make_host_mesh
         from repro.models import build_model
         from repro.parallel.sharding import param_specs
 
@@ -29,7 +30,7 @@ def test_save_on_8_restore_on_4(tmp_path):
         params = model.init(jax.random.PRNGKey(0))
 
         # place on 4x2 mesh, save
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_host_mesh(4, 2)
         specs = param_specs(model, mesh_a)
         sh = jax.tree.map(lambda s: NamedSharding(mesh_a, s), specs,
                           is_leaf=lambda x: isinstance(x, P))
@@ -38,7 +39,7 @@ def test_save_on_8_restore_on_4(tmp_path):
         mgr.save(1, placed)
 
         # restore resharded onto a 2x2 mesh (elastic shrink)
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_b = make_host_mesh(2, 2)
         specs_b = param_specs(model, mesh_b)
         restored, _ = reshard_restore(mgr, params, mesh_b, specs_b)
         for a, b in zip(jax.tree.leaves(params),

@@ -22,7 +22,8 @@ def test_moe_all_to_all_matches_dense_reference():
         from repro.parallel.collectives import moe_all_to_all_sharded
 
         E, K, T, d, ff = 8, 2, 64, 16, 32
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         xt = jnp.asarray(rng.normal(size=(T, d)), jnp.float32)
         logits = jnp.asarray(rng.normal(size=(T, E)), jnp.float32)
@@ -45,9 +46,10 @@ def test_moe_all_to_all_matches_dense_reference():
             return jnp.einsum("tf,tfd->td", jax.nn.relu(h),
                               w2_l[local_eid])
 
-        out = moe_all_to_all_sharded(
-            mesh, xt, top_e, top_w, (w1, w2), act, n_experts=E,
-            capacity_factor=8.0)   # high capacity: no drops -> exact
+        with jax.set_mesh(mesh):
+            out = moe_all_to_all_sharded(
+                mesh, xt, top_e, top_w, (w1, w2), act, n_experts=E,
+                capacity_factor=8.0)   # high capacity: no drops -> exact
         err = float(jnp.max(jnp.abs(out - ref)))
         assert err < 1e-4, err
         print("OK", err)
@@ -70,7 +72,8 @@ def test_moe_all_to_all_wire_is_true_all_to_all():
         from repro.hw.hlo_parse import analyze_hlo
 
         E, K, T, d, ff = 8, 2, 4096, 64, 128
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
         def f(xt, top_e, top_w, w1, w2):
             def act(local_eid, x, weights):
@@ -81,10 +84,11 @@ def test_moe_all_to_all_wire_is_true_all_to_all():
                                           (w1, w2), act, n_experts=E)
 
         sds = jax.ShapeDtypeStruct
-        comp = jax.jit(f).lower(
-            sds((T, d), jnp.float32), sds((T, K), jnp.int32),
-            sds((T, K), jnp.float32), sds((E, d, ff), jnp.float32),
-            sds((E, ff, d), jnp.float32)).compile()
+        with jax.set_mesh(mesh):
+            comp = jax.jit(f).lower(
+                sds((T, d), jnp.float32), sds((T, K), jnp.int32),
+                sds((T, K), jnp.float32), sds((E, d, ff), jnp.float32),
+                sds((E, ff, d), jnp.float32)).compile()
         an = analyze_hlo(comp.as_text())
         assert an.collective["all-to-all_count"] >= 3, an.collective
         # all-reduce traffic must be far below the token-tensor size

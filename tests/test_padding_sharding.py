@@ -63,15 +63,15 @@ def test_attention_seq_fallback_when_heads_dont_divide():
         import jax, jax.numpy as jnp
         import repro.models.common as cm
         from repro.hw.hlo_parse import analyze_hlo
-        from repro.parallel.sharding import use_mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(2, 4)
         B, S, H, D = 4, 64, 6, 8     # H=6 does not divide model=4
 
         def f(q, k, v):
             return cm.chunked_attention(q, k, v, causal=True, block_k=32)
 
         sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = jax.jit(f).lower(sds(B, S, H, D), sds(B, S, H, D),
                                     sds(B, S, H, D)).compile()
         an = analyze_hlo(comp.as_text())

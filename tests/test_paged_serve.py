@@ -256,8 +256,11 @@ def test_paged_pool_backpressure_serves_everything():
 
 @pytest.mark.parametrize("family", ["transformer", "ssm"] + _HEAVY)
 def test_batched_bucketed_prefill_matches_single(family):
-    """Right-padded bucket prefill with prompt_lens masking is bit-exact
-    against per-prompt prefill for every family."""
+    """Right-padded bucket prefill with prompt_lens masking matches
+    per-prompt prefill for every family: same greedy token, logits within
+    1e-5.  Not bit-exact: XLA's float32 reduction order changes with the
+    batch shape, and the measured difference is at most 2.2e-7 — a leak
+    through the padding mask would be orders of magnitude larger."""
     model, params, cfg = _smoke(FAMILY_ARCHS[family])
     rng = np.random.default_rng(4)
     plens = [5, 9, 12]
@@ -279,8 +282,10 @@ def test_batched_bucketed_prefill_matches_single(family):
         ex = {k: v[i:i + 1] for k, v in extras.items()}
         l1, _ = model.prefill(params, jnp.asarray(p[None]), remat=False,
                               **ex, **kw)
-        assert np.array_equal(np.asarray(logits_b[i]), np.asarray(l1[0])), \
-            (family, i)
+        got, want = np.asarray(logits_b[i]), np.asarray(l1[0])
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5,
+                                   err_msg=f"{family} row {i}")
+        assert got.argmax() == want.argmax(), (family, i)
 
 
 # ---------------------------------------------------------------------------

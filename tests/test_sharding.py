@@ -30,8 +30,9 @@ def test_param_specs_divisible():
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config
         from repro.models import build_model
+        from repro.launch.mesh import make_host_mesh
         from repro.parallel.sharding import param_specs
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_host_mesh(4, 4)
         results = {}
         for arch in ("llama3.2-1b", "granite-moe-1b-a400m", "mamba2-370m",
                      "zamba2-7b", "seamless-m4t-medium"):
@@ -64,8 +65,9 @@ def test_cache_specs_decode_sharding():
         import jax
         from repro.configs import get_config, get_shape
         from repro.models import build_model
+        from repro.launch.mesh import make_host_mesh
         from repro.parallel.sharding import cache_specs
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_host_mesh(4, 4)
         # batch-shardable decode: batch dim -> data
         m = build_model(get_config("llama3.2-1b"))
         c = m._cache_struct(B=8, max_seq=4096)
@@ -94,13 +96,13 @@ def test_small_mesh_train_step_runs():
         from repro.parallel.sharding import param_specs, batch_specs
         from repro.train import OptimizerConfig, make_train_step, \\
             init_train_state
-        from repro.parallel.sharding import use_mesh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(4, 2)
         cfg = smoke_config(REGISTRY["llama3.2-1b"])
         model = build_model(cfg, block_k=16)
         step = make_train_step(model, OptimizerConfig(lr=1e-3),
                                accum_steps=2, remat=True)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_train_state(model, jax.random.PRNGKey(0))
             rng = np.random.default_rng(0)
             batch = {k: jnp.asarray(rng.integers(0, cfg.vocab_size,
@@ -118,6 +120,50 @@ def test_small_mesh_train_step_runs():
         assert np.isfinite(loss), loss
         print("OK", loss)
     """), devices=8)
+    assert "OK" in out
+
+
+@pytest.mark.slow
+def test_kernel_attention_under_mesh_matches_unsharded():
+    """Under a mesh the Pallas attention kernel runs per device (the
+    compiler cannot partition it); loss and grads match the run without a
+    mesh.  Kernels are steered to interpret mode for the CPU."""
+    out = run_sub(textwrap.dedent("""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        import repro.models.common as cm
+        from repro.configs import REGISTRY, smoke_config
+        from repro.launch.mesh import make_host_mesh
+        from repro.models import build_model
+        from repro.parallel.sharding import param_specs
+        cm._attention_kernels = lambda: {"interpret": True}
+        cfg = dataclasses.replace(smoke_config(REGISTRY["llama3.2-1b"]),
+                                  compute_dtype="float32")
+        model = build_model(cfg, block_k=16)
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        batch = {k: jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)),
+                                jnp.int32) for k in ("tokens", "targets")}
+        grad = jax.jit(jax.value_and_grad(
+            lambda p, b: model.loss(p, b, remat=False)[0]))
+        l_ref, g_ref = grad(params, batch)
+        mesh = make_host_mesh(2, 2)
+        shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             param_specs(model, mesh),
+                             is_leaf=lambda x: isinstance(x, P))
+        with jax.set_mesh(mesh):
+            hlo = grad.lower(params, batch).as_text()
+            l_sh, g_sh = grad(jax.device_put(params, shard),
+                              jax.device_put(batch,
+                                             NamedSharding(mesh, P("data"))))
+        assert "shard_map" in hlo or "manual" in hlo.lower()
+        assert abs(float(l_ref) - float(l_sh)) < 1e-5, (l_ref, l_sh)
+        for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_sh)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+        print("OK", float(l_ref), float(l_sh))
+    """), devices=4)
     assert "OK" in out
 
 

@@ -53,6 +53,35 @@ def test_checkpoint_restart_on_failure(tmp_path):
     assert steps.count(5) >= 2
 
 
+def test_restart_resumes_bit_identically(tmp_path):
+    """The step donates its input state; a run that fails and restarts
+    from its checkpoint still ends on exactly the uninterrupted run's
+    state and losses."""
+    clean = make_trainer(tmp_path / "clean", total_steps=12)
+    clean.run()
+    failed = make_trainer(tmp_path / "failed", total_steps=12, fail_at=(10,))
+    out = failed.run()
+    assert out["restarts"] == 1
+    losses = {h["step"]: h["loss"] for h in clean.history}
+    for h in failed.history:
+        assert h["loss"] == losses[h["step"]], h
+    final = [np.load(tmp_path / run / "ckpt_00000012" / "proc_0.npz")
+             for run in ("clean", "failed")]
+    assert sorted(final[0].files) == sorted(final[1].files)
+    for key in final[0].files:
+        np.testing.assert_array_equal(final[0][key], final[1][key])
+
+
+def test_trainer_donates_the_state(tmp_path):
+    trainer = make_trainer(tmp_path)
+    state = trainer._fresh_state()
+    batch = {k: jax.numpy.asarray(v)
+             for k, v in trainer.pipeline.next_batch().items()}
+    new_state, _ = trainer.train_step(state, batch)
+    assert all(x.is_deleted() for x in jax.tree.leaves(state))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(new_state))
+
+
 def test_too_many_failures_raises(tmp_path):
     trainer = make_trainer(tmp_path, total_steps=10,
                            fail_at=(1, 2, 3, 4, 5, 6))
