@@ -138,6 +138,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, o, do, lse)
 
@@ -162,6 +163,7 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
                    jax.ShapeDtypeStruct((BH, Sk, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, o, do, lse)
     return dq, dk, dv

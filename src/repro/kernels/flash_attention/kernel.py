@@ -139,5 +139,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, D), jnp.float32),   # accumulator
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)

@@ -173,6 +173,7 @@ def paged_flash_decode(q, k_pages, v_pages, block_tables, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(pos, jnp.int32),
       *operands)

@@ -34,5 +34,6 @@ def rmsnorm_rows(x, w, *, eps: float = 1e-5, block_rows: int = 256,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x, w)

@@ -97,6 +97,7 @@ def ssd_scan(x, a, Bm, Cm, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        name="ssd_scan",
         interpret=interpret,
     )(x, a, Bm, Cm)
     return y, h_final

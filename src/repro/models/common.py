@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import scope
+
 Params = Dict[str, Any]
 
 # ---------------------------------------------------------------------------
@@ -145,6 +147,7 @@ def init_norm(b: ParamBuilder, d: int, kind: str) -> Params:
     return p
 
 
+@scope("norm")
 def apply_norm(p: Params, x: jnp.ndarray, kind: str,
                eps: float = 1e-5) -> jnp.ndarray:
     dt = x.dtype
@@ -362,6 +365,7 @@ def _per_device(kernel, q, k, v):
                          out_specs=q_spec, check_vma=False)(q, k, v)
 
 
+@scope("attn")
 def attention_block(p: Params, x: jnp.ndarray, *, cfg_theta: float,
                     positional: str, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, q_offset: int = 0,
@@ -423,6 +427,7 @@ def init_mlp(b: ParamBuilder, d: int, d_ff: int, activation: str) -> Params:
     }
 
 
+@scope("mlp")
 def apply_mlp(p: Params, x: jnp.ndarray, activation: str) -> jnp.ndarray:
     wd = lambda w: cast_sharded(w, x.dtype, "batch", "model")
     if activation == "swiglu":
@@ -462,6 +467,7 @@ def init_moe(b: ParamBuilder, d: int, d_ff: int, n_experts: int,
     return p
 
 
+@scope("mlp")
 def apply_moe(p: Params, x: jnp.ndarray, *, n_experts: int, top_k: int,
               capacity_factor: float, activation: str,
               shared_expert: bool, drop: bool = True
@@ -749,6 +755,7 @@ def init_embedding(b: ParamBuilder, vocab: int, d: int,
     return p
 
 
+@scope("embed")
 def embed_tokens(p: Params, tokens: jnp.ndarray, dtype,
                  pos_offset: int = 0) -> jnp.ndarray:
     x = jnp.take(p["wte"], tokens, axis=0).astype(dtype)
@@ -761,6 +768,7 @@ def embed_tokens(p: Params, tokens: jnp.ndarray, dtype,
 _VOCAB_PAD = 512
 
 
+@scope("head")
 def unembed(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     """Project to vocab logits, padded to a 512-multiple so the vocab dim
     shards over the model axis (odd vocab sizes — 50280, 49155, 151655... —

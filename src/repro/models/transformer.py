@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..configs.base import ModelConfig, ShapeConfig
+from ..obs.scopes import scope
 from . import common as cm
 from .common import ParamBuilder, Params
 
@@ -200,7 +201,9 @@ class DecoderLM:
             x = x[:, patch.shape[1]:]          # loss only over text positions
         logits = self.logits(params, x)
         mask = batch.get("mask")
-        loss = cm.softmax_cross_entropy(logits, targets, mask, z_loss=1e-4)
+        with scope("head"):
+            loss = cm.softmax_cross_entropy(logits, targets, mask,
+                                            z_loss=1e-4)
         metrics = {"ce_loss": loss}
         if cfg.is_moe:
             loss = (loss + cfg.moe.aux_loss_weight * aux["load_balance"]

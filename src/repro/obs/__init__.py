@@ -8,9 +8,11 @@
 * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` with counters /
   gauges / histograms (p50/p99);
 * :mod:`repro.obs.ledger` — the three-tier energy-conservation ledger
-  and the ``check_*`` reconciliation functions.
+  and the ``check_*`` reconciliation functions;
+* :mod:`repro.obs.scopes` — the vocabulary of ``jax.named_scope`` names
+  on the training path (:data:`SCOPES`) and :func:`scope`.
 
-Imports only :mod:`repro.core` (+ stdlib / numpy), so every other
+Imports only :mod:`repro.core` (+ stdlib / numpy / jax), so every other
 subpackage may depend on it without cycles.
 """
 from .ledger import (EnergyLedger, check_executor, check_fleet,
@@ -22,6 +24,7 @@ from .schema import (CATEGORIES, KINDS, OBS_SCHEMA_VERSION,
                      from_recovery_books, from_replica_events,
                      ingest_legacy_streams, make_event,
                      validate_trace_dict)
+from .scopes import SCOPES, scope
 from .tracer import NULL_TRACER, NullTracer, Tracer
 
 __all__ = [
@@ -33,5 +36,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "EnergyLedger", "segment_breakdown", "executor_ledger",
     "replica_ledger", "fleet_ledger", "check_executor",
-    "check_replica", "check_fleet",
+    "check_replica", "check_fleet", "SCOPES", "scope",
 ]

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import scope
 from .optimizer import OptimizerConfig, adamw_update, init_opt_state
 from .grad import accumulate, zeros_like_f32, compress_grads, \
     decompress_grads
@@ -82,7 +83,8 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
 
         if accum_steps == 1:
             (loss, metrics), grads = grad_fn(state.params, batch, step_rng)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            with scope("grad_accum"):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         else:
             def split(x):
                 return x.reshape((accum_steps, x.shape[0] // accum_steps)
@@ -93,10 +95,12 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
                 acc, rng = carry
                 rng, k = jax.random.split(rng)
                 (loss, metrics), grads = grad_fn(state.params, mb, k)
-                acc = accumulate(acc, grads, 1.0 / accum_steps)
+                with scope("grad_accum"):
+                    acc = accumulate(acc, grads, 1.0 / accum_steps)
                 return (acc, rng), (loss, metrics)
 
-            acc0 = zeros_like_f32(state.params)
+            with scope("grad_accum"):
+                acc0 = zeros_like_f32(state.params)
             (grads, _), (losses, metricses) = lax.scan(
                 body, (acc0, step_rng), micro)
             loss = losses.mean()
@@ -107,8 +111,9 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
             grads, _ = compress_grads(grads, crng)
             grads = decompress_grads(grads)
 
-        params, opt, opt_metrics = adamw_update(
-            state.params, grads, state.opt, opt_cfg)
+        with scope("opt"):
+            params, opt, opt_metrics = adamw_update(
+                state.params, grads, state.opt, opt_cfg)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss
