@@ -4,6 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention.backward import flash_attention_bwd
+from repro.kernels.flash_attention.kernel import (causal_subtiles,
+                                                  flash_attention_fwd)
 from repro.kernels.flash_attention.ops import flash_attention_train
 from repro.kernels.flash_attention.ref import attention_ref
 
@@ -65,3 +68,43 @@ def test_flash_fwd_value_consistent_with_train_variant():
     o2 = flash_attention_train(q, k, v, True, 0, 16, 16, True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5,
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("S,H,KV,block,subtile,causal,window,computed", [
+    (64, 2, 2, 64, 16, True, 0, 10),     # one diagonal tile, 10 of 16
+    (64, 2, 2, 16, 8, True, 0, 3),       # 4x4 grid, 6 tiles skipped
+    (56, 2, 2, 16, 8, True, 0, None),    # padded keys: masked tiles
+    (64, 2, 2, 16, 8, True, 24, None),   # window: masked tiles
+    (64, 2, 2, 64, 16, False, 0, None),  # non-causal: the whole square
+    (64, 4, 2, 32, 16, True, 0, 3),      # GQA, 2x2 grid
+])
+def test_flash_kernels_skip_masked_work(S, H, KV, block, subtile, causal,
+                                        window, computed):
+    """The forward and both backward kernels, called with a sub-tile edge
+    of their own, against ``jax.vjp`` of the float32 oracle."""
+    B, D = 2, 16
+    G, Sp = H // KV, -(-S // block) * block
+    pairs, _ = causal_subtiles(block, block, subtile, causal, window,
+                               Sp > S)
+    assert (None if pairs is None else len(pairs)) == computed
+    q, k, v, do = (jnp.asarray(RNG.normal(size=(n, S, D)), jnp.float32)
+                   for n in (B * H, B * KV, B * KV, B * H))
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, Sp - S), (0, 0)))
+    rep = lambda x: jnp.repeat(pad(x), G, axis=0)
+    o, lse = flash_attention_fwd(
+        pad(q), pad(k), pad(v), causal=causal, window=window,
+        block_q=block, block_k=block, group=G, kv_len=S, return_lse=True,
+        interpret=True, _subtile=subtile)
+    grads = flash_attention_bwd(
+        pad(q), rep(k), rep(v), o, pad(do), lse, causal=causal,
+        window=window, block_q=block, block_k=block, kv_len=S,
+        interpret=True, _subtile=subtile)
+    dk, dv = (g[:, :S].reshape(B * KV, G, S, D).sum(1) for g in grads[1:])
+
+    o_ref, vjp = jax.vjp(lambda q, k, v: attention_ref(
+        q, k, v, causal=causal, window=window, group=G), q, k, v)
+    for name, a, b in zip(("o", "dq", "dk", "dv"),
+                          (o[:, :S], grads[0][:, :S], dk, dv),
+                          (o_ref, *vjp(do))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)

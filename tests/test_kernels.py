@@ -6,6 +6,9 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import attention_ref, flash_attention
+from repro.kernels.flash_attention.kernel import (SUBTILE, SUBTILE_COUNTS,
+                                                  causal_subtiles,
+                                                  flash_attention_fwd)
 from repro.kernels.rmsnorm import rmsnorm, rmsnorm_ref
 from repro.kernels.ssd_scan import ssd, ssd_ref
 
@@ -23,6 +26,7 @@ def _tol(dtype):
     (1, 48, 48, 4, 4, 16),
     (2, 32, 64, 4, 1, 32),
     (1, 96, 96, 8, 2, 8),
+    (1, 64, 32, 2, 2, 16),   # more query tiles than key tiles
 ])
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -71,6 +75,47 @@ def test_flash_attention_softcap_and_padding():
         .reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(o), np.asarray(r), rtol=2e-5,
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("block,c,causal,window,padded,computed,total", [
+    (1024, 256, True, 0, False, 10, 16),
+    (1024, 128, True, 0, False, 36, 64),
+    (1024, 512, True, 0, False, 3, 4),
+    (128, 256, True, 0, False, 1, 1),      # c capped at the block
+    (1024, 256, False, 0, False, None, 16),  # non-causal: every sub-tile
+    (1024, 256, True, 512, False, None, 16),  # window: masked tiles
+    (1024, 256, True, 0, True, None, 16),   # padded keys: masked tiles
+    (384, 256, True, 0, False, None, 1),    # c does not divide the block
+])
+def test_causal_subtiles(block, c, causal, window, padded, computed, total):
+    pairs, n = causal_subtiles(block, block, c, causal, window, padded)
+    assert n == total
+    assert (None if pairs is None else len(pairs)) == computed
+    if pairs is not None:   # the lower triangle, row by row
+        assert list(pairs) == sorted(pairs)
+        assert all(j <= i for i, j in pairs)
+
+
+def test_causal_subtiles_needs_square_blocks():
+    assert causal_subtiles(1024, 512, 256, True, 0) == (None, 16)
+
+
+def test_subtiles_counted_per_lowering():
+    """Each trace of the forward adds its plan to the registry, so the
+    share of the square computed is known without running anything."""
+    def counts():
+        snap = SUBTILE_COUNTS.snapshot()
+        return [snap.get(f"flash_subtiles_{k}{{kernel=flash_fwd}}",
+                         {"value": 0.0})["value"]
+                for k in ("computed", "total")]
+
+    before = counts()
+    x = jax.ShapeDtypeStruct((2, 1024, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention_fwd(
+        q, k, v, block_q=1024, block_k=1024, interpret=True), x, x, x)
+    after = counts()
+    pairs, total = causal_subtiles(1024, 1024, SUBTILE, True, 0)
+    assert [a - b for a, b in zip(after, before)] == [len(pairs), total]
 
 
 @pytest.mark.parametrize("shape", [(4, 32), (3, 17, 32), (2, 5, 7, 64)])

@@ -11,6 +11,7 @@ The topology is described only inside the module fixture: one process at
 a time may load the TPU library, so it must not happen at import.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +36,32 @@ def one_chip():
 
 def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_KERNEL = re.compile(
+    r'op_name="[^"]*?(flash_fwd|flash_bwd_dq|flash_bwd_dkv)\W')
+
+
+def _flash_calls(text):
+    """``{kernel name: bench.trace.Op}`` for each flash kernel call in the
+    compiled text, the instruction rebuilt as a profiler trace names it
+    (result and operand shapes with the call)."""
+    from bench.trace import Op
+    calls = {}
+    for line in text.splitlines():
+        name = _KERNEL.search(line)
+        if 'custom_call_target="tpu_custom_call"' not in line or not name:
+            continue
+        result = re.match(r"\s*%?[\w.\-]+ = (.*?) custom-call\(",
+                          line).group(1)
+        constraints = line.split("operand_layout_constraints={", 1)[1]
+        constraints = re.sub(r"\{[^{}]*\}", "", constraints).split("}")[0]
+        operands = re.findall(r"[a-z]+\d*\[[\d,]*\]", constraints)
+        calls[name.group(1)] = Op(0.0, 1.0, (
+            f"%{name.group(1)}.1 = {result} custom-call("
+            f"{', '.join(operands)}), "
+            f'custom_call_target="tpu_custom_call"'))
+    return calls
 
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
@@ -68,4 +95,17 @@ def test_flash_attention_train_compiles(one_chip, backward):
     if backward:
         fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                       argnums=(0, 1, 2))
-    assert "tpu_custom_call" in _compiled_text(fn, *qkv)
+    # the benchmark's roofline readers find each kernel by its operand and
+    # result shapes: the calls keep their names and shapes
+    from bench.kernels import flash_bwd, flash_fwd
+    calls = _flash_calls(_compiled_text(fn, *qkv))
+    names = ["flash_fwd"] + (["flash_bwd_dq", "flash_bwd_dkv"]
+                             if backward else [])
+    assert sorted(calls) == sorted(names)
+    assert flash_fwd.call_cost(calls["flash_fwd"]) == \
+        flash_fwd.cost(B * H, S, D)
+    if backward:
+        assert flash_bwd.call_cost(calls["flash_bwd_dq"]) == \
+            flash_bwd.cost(B * H, S, D)
+        assert flash_bwd.call_cost(calls["flash_bwd_dkv"]) == (0.0, 0.0)
+        assert flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
