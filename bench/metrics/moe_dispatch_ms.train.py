@@ -1,0 +1,11 @@
+"""Device time, in ms per traced step and the mean over chips, of the ops
+in the program's ``dispatch`` sub-scope of an expert layer
+(``models/common.py:apply_routed_moe``, inside ``mlp``): the dispatch:
+the sort of the assignments by expert, the gather of their rows, and the
+weighted combine back to token order. None for a program without expert
+layers."""
+from bench import moe_scopes
+
+
+def read(ctx):
+    return moe_scopes.step_ms(ctx, "dispatch")

@@ -1,0 +1,9 @@
+"""Device time, in ms per traced step and the mean over chips, of the ops
+in the program's ``shared`` sub-scope of an expert layer
+(``models/common.py:apply_routed_moe``, inside ``mlp``): the shared
+expert's SwiGLU. None for a program without expert layers."""
+from bench import moe_scopes
+
+
+def read(ctx):
+    return moe_scopes.step_ms(ctx, "shared")

@@ -1,33 +1,36 @@
 """Architecture registry: ``--arch <id>`` resolution.
 
 The ten assigned architectures plus the paper's own case-study model
-(``gpt3-xl``).  IDs use the assignment spelling (dots and dashes); module
+(``gpt3-xl``) and Moonlight-16B-A3B (``moonlight-16b-a3b``, latent attention
+and dropless fine-grained experts).  IDs use the assignment spelling (dots and dashes); module
 names are the sanitized forms.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
-from .base import ModelConfig, MoEConfig, SSMConfig, ShapeConfig, \
-    smoke_config, config_summary
+from .base import MLAConfig, ModelConfig, MoEConfig, SSMConfig, \
+    ShapeConfig, smoke_config, config_summary
 from .shapes import SHAPES, get_shape, smoke_shape, TRAIN_4K, PREFILL_32K, \
     DECODE_32K, LONG_500K, PAPER_GPT3XL
 
 from . import (llama3_2_3b, llama3_2_1b, nemotron_4_340b, yi_34b,
                granite_moe_1b_a400m, llama4_scout_17b_a16e,
                seamless_m4t_medium, internvl2_1b, mamba2_370m, zamba2_7b,
-               gpt3_xl)
+               gpt3_xl, moonlight_16b_a3b)
 
 _MODULES = (llama3_2_3b, nemotron_4_340b, llama3_2_1b, yi_34b,
             granite_moe_1b_a400m, llama4_scout_17b_a16e,
             seamless_m4t_medium, internvl2_1b, mamba2_370m, zamba2_7b,
-            gpt3_xl)
+            gpt3_xl, moonlight_16b_a3b)
 
 REGISTRY: Dict[str, ModelConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}
 
-# The ten assigned architectures (gpt3-xl is the paper's extra case study).
+# The ten assigned architectures (gpt3-xl is the paper's extra case study,
+# moonlight-16b-a3b a benchmark configuration).
 ASSIGNED: List[str] = [m.CONFIG.name for m in _MODULES
-                       if m.CONFIG.name != "gpt3-xl"]
+                       if m.CONFIG.name not in ("gpt3-xl",
+                                                "moonlight-16b-a3b")]
 
 # Canonical assigned shapes (paper_gpt3xl is extra).
 ASSIGNED_SHAPES: List[str] = ["train_4k", "prefill_32k", "decode_32k",
@@ -65,7 +68,7 @@ def all_cells(include_skipped: bool = False):
 
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "SSMConfig", "ShapeConfig",
+    "MLAConfig", "ModelConfig", "MoEConfig", "SSMConfig", "ShapeConfig",
     "REGISTRY", "ASSIGNED", "ASSIGNED_SHAPES", "SHAPES",
     "get_config", "get_shape", "smoke_config", "smoke_shape",
     "cell_is_runnable", "all_cells", "config_summary",

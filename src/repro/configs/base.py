@@ -32,6 +32,40 @@ class MoEConfig:
     aux_loss_weight: float = 0.01
     router_z_loss_weight: float = 1e-3
 
+    # --- dropless routing over held experts (DeepSeek-V3 style) ----------
+    # Set ``dropless`` for the layer of ``models/common.py:apply_routed_moe``:
+    # sigmoid scores, every token reaches its experts, none is dropped, and
+    # this chip computes only the ``experts_held`` (0: all) experts it holds.
+    dropless: bool = False
+    experts_held: int = 0
+    d_expert: int = 0              # expert width; 0 -> the model's d_ff
+    n_shared_experts: int = 0      # shared SwiGLU of n_shared * d_expert
+    routed_scale: float = 1.0      # weights times this after top-k norm
+    # per-expert selection bias moved by +-bias_rate after each step toward
+    # the mean load (auxiliary-loss-free balancing); 0 = no bias
+    bias_rate: float = 0.0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention sub-config (DeepSeek-V2/V3): keys and
+    values come from one latent of ``kv_lora_rank``; queries and keys are
+    ``qk_nope_head_dim + qk_rope_head_dim`` wide, values ``v_head_dim``.
+    ``kv_lora_rank`` 0 means ordinary attention."""
+
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    @property
+    def enabled(self) -> bool:
+        return self.kv_lora_rank > 0
+
 
 @dataclass(frozen=True)
 class SSMConfig:
@@ -91,8 +125,12 @@ class ModelConfig:
     # ``attn_every`` SSM blocks (zamba2-style weight sharing)
     attn_every: int = 0
 
+    # leading layers with a dense MLP of width d_ff before the MoE layers
+    n_dense_layers: int = 0
+
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
+    mla: MLAConfig = field(default_factory=MLAConfig)
 
     # --- numerics ---------------------------------------------------------
     param_dtype: str = "float32"
@@ -108,6 +146,11 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def __post_init__(self):
+        # sub-configs may come as the plain dicts of a JSON file
+        for name, kind in (("moe", MoEConfig), ("ssm", SSMConfig),
+                           ("mla", MLAConfig)):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, kind(**getattr(self, name)))
         if self.family not in FAMILIES:
             raise ValueError(f"unknown family {self.family!r}")
         if self.n_heads and self.n_heads % max(self.n_kv_heads, 1) != 0:
@@ -148,6 +191,14 @@ class ModelConfig:
             total += self.max_train_seq * 0  # counted per-shape, negligible
 
         def attn_params():
+            m, H = self.mla, self.n_heads
+            if m.enabled:
+                return (d * H * hd
+                        + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                        + m.kv_lora_rank
+                        + m.kv_lora_rank * H * (m.qk_nope_head_dim
+                                                + m.v_head_dim)
+                        + H * m.v_head_dim * d)
             return d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
                 + (self.n_heads * hd) * d
 
@@ -181,14 +232,19 @@ class ModelConfig:
             total += 2 * d * (self.n_heads * hd) * 2 + mlp_params(ff) + 4 * d
             active = total
         elif self.is_moe:
+            mo = self.moe
             per_dense = attn_params() + 4 * d
-            per_expert = mlp_params(ff)
-            shared = mlp_params(ff) if self.moe.shared_expert else 0
-            total += self.n_layers * (per_dense + self.moe.n_experts * per_expert
-                                      + shared + d * self.moe.n_experts)
-            active = (total
-                      - self.n_layers * (self.moe.n_experts - self.moe.top_k)
-                      * per_expert)
+            per_expert = mlp_params(mo.d_expert or ff)
+            shared = mlp_params(ff) if mo.shared_expert else 0
+            shared += mlp_params(mo.n_shared_experts * (mo.d_expert or ff))
+            n_moe = self.n_layers - self.n_dense_layers
+            total += self.n_dense_layers * (per_dense + mlp_params(ff))
+            total += n_moe * (per_dense + mo.held * per_expert
+                              + shared + d * mo.n_experts)
+            # a token reaches top_k of n_experts; held / n_experts of those
+            # are computed here
+            active = total - n_moe * (
+                mo.held - mo.top_k * mo.held / mo.n_experts) * per_expert
         else:
             n_dec = self.n_layers
             per = attn_params() + mlp_params(ff) + 4 * d
@@ -241,7 +297,14 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         max_train_seq=4096,
     )
     if cfg.is_moe:
-        kw["moe"] = replace(cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2))
+        kw["moe"] = replace(cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2),
+                            experts_held=min(cfg.moe.experts_held, 2),
+                            d_expert=32 if cfg.moe.d_expert else 0)
+    if cfg.mla.enabled:
+        # queries and keys 16 + 8 wide, values 16: still Dqk != Dv
+        kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                              qk_rope_head_dim=8, v_head_dim=16)
+        kw["head_dim"] = 24
     if cfg.family in ("ssm", "hybrid"):
         kw["ssm"] = replace(cfg.ssm, state_dim=16, head_dim=16, chunk_size=16)
     if cfg.family == "hybrid":

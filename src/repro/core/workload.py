@@ -106,11 +106,17 @@ class WorkloadBuilder:
         H = max(cfg.n_heads // self.tp, 1)
         KVh = max(cfg.n_kv_heads // self.tp, 1)
         hd = cfg.resolved_head_dim or (d_in // max(cfg.n_heads, 1))
+        hv = self._v_head_dim(hd)
         S_kv = S_kv or S
         eff_kv = min(window, S_kv) if window else S_kv
         frac = 0.5 if (causal and not window and S == S_kv) else 1.0
-        self._gemm(f"{prefix}GEMM qkv", B * S, (H + 2 * KVh) * hd, d_in,
-                   inv=inv)
+        if cfg.mla.enabled:
+            KVh = H
+            for name, N, K in self._mla_projections(H, d_in):
+                self._gemm(f"{prefix}GEMM {name}", B * S, N, K, inv=inv)
+        else:
+            self._gemm(f"{prefix}GEMM qkv", B * S, (H + 2 * KVh) * hd,
+                       d_in, inv=inv)
         if cfg.positional == "rope":
             self._elem(f"{prefix}RoPE", "permute",
                        B * S * (H + KVh) * hd, rw=2, inv=inv)
@@ -123,12 +129,12 @@ class WorkloadBuilder:
                          + score_elems), inv=inv)
         self._elem(f"{prefix}Softmax", "softmax", score_elems, rw=2, inv=inv,
                    flops_per=4.0)
-        self._emit(f"{prefix}GEMM av", "gemm", 2 * score_elems * hd,
-                   db * (score_elems + panel * B * eff_kv * KVh * hd
-                         + B * S * H * hd), inv=inv)
-        self._elem(f"{prefix}Unpermute", "permute", B * S * H * hd, rw=2,
+        self._emit(f"{prefix}GEMM av", "gemm", 2 * score_elems * hv,
+                   db * (score_elems + panel * B * eff_kv * KVh * hv
+                         + B * S * H * hv), inv=inv)
+        self._elem(f"{prefix}Unpermute", "permute", B * S * H * hv, rw=2,
                    inv=inv)
-        self._gemm(f"{prefix}GEMM proj", B * S, d_out, H * hd, inv=inv)
+        self._gemm(f"{prefix}GEMM proj", B * S, d_out, H * hv, inv=inv)
         if self.include_comm and self.tp > 1:
             self._emit(f"{prefix}AllReduce attn", "allreduce", 0,
                        db * B * S * d_out / 4,
@@ -144,21 +150,24 @@ class WorkloadBuilder:
         H = max(cfg.n_heads // self.tp, 1)
         KVh = max(cfg.n_kv_heads // self.tp, 1)
         hd = cfg.resolved_head_dim or (d_in // max(cfg.n_heads, 1))
+        hv = self._v_head_dim(hd)
+        if cfg.mla.enabled:
+            KVh = H
         S_kv = S_kv or S
         eff_kv = min(window, S_kv) if window else S_kv
         frac = 0.5 if (causal and not window and S == S_kv) else 1.0
         score_elems = B * H * S * eff_kv * frac
         panel = self.GEMM_PANEL_REUSE
-        self._gemm_bwd(f"{prefix}GEMM proj", B * S, d_out, H * hd, inv=inv)
-        self._elem(f"{prefix}Permute bwd", "permute", B * S * H * hd, rw=2,
+        self._gemm_bwd(f"{prefix}GEMM proj", B * S, d_out, H * hv, inv=inv)
+        self._elem(f"{prefix}Permute bwd", "permute", B * S * H * hv, rw=2,
                    inv=inv, phase="bwd")
         # d(av): dP = dO V^T ; dV = P^T dO
-        self._emit(f"{prefix}GEMM av dgrad", "gemm", 2 * score_elems * hd,
-                   db * (panel * (B * S * H * hd + B * eff_kv * KVh * hd)
+        self._emit(f"{prefix}GEMM av dgrad", "gemm", 2 * score_elems * hv,
+                   db * (panel * (B * S * H * hv + B * eff_kv * KVh * hv)
                          + score_elems), inv=inv, phase="bwd")
-        self._emit(f"{prefix}GEMM av wgrad", "gemm", 2 * score_elems * hd,
-                   db * (score_elems + panel * B * S * H * hd
-                         + B * eff_kv * KVh * hd), inv=inv, phase="bwd")
+        self._emit(f"{prefix}GEMM av wgrad", "gemm", 2 * score_elems * hv,
+                   db * (score_elems + panel * B * S * H * hv
+                         + B * eff_kv * KVh * hv), inv=inv, phase="bwd")
         self._elem(f"{prefix}Softmax bwd", "softmax", score_elems, rw=3,
                    inv=inv, phase="bwd", flops_per=4.0)
         self._emit(f"{prefix}GEMM qk dgrad", "gemm", 2 * score_elems * hd,
@@ -167,8 +176,37 @@ class WorkloadBuilder:
         self._emit(f"{prefix}GEMM qk wgrad", "gemm", 2 * score_elems * hd,
                    db * (score_elems + panel * B * S * H * hd
                          + B * eff_kv * KVh * hd), inv=inv, phase="bwd")
-        self._gemm_bwd(f"{prefix}GEMM qkv", B * S, (H + 2 * KVh) * hd, d_in,
-                       inv=inv)
+        if cfg.mla.enabled:
+            for name, N, K in self._mla_projections(H, d_in):
+                self._gemm_bwd(f"{prefix}GEMM {name}", B * S, N, K, inv=inv)
+        else:
+            self._gemm_bwd(f"{prefix}GEMM qkv", B * S, (H + 2 * KVh) * hd,
+                           d_in, inv=inv)
+
+    def _v_head_dim(self, hd):
+        """Width of a value head: latent attention's own, else ``hd``."""
+        m = self.cfg.mla
+        return m.v_head_dim if m.enabled else hd
+
+    def _mla_projections(self, H, d_in):
+        """(name, N, K) of latent attention's input projections: queries,
+        the KV latent with the shared RoPE key, and the latent's up
+        projection to keys and values."""
+        m = self.cfg.mla
+        return [("q", H * (m.qk_nope_head_dim + m.qk_rope_head_dim), d_in),
+                ("kv latent", m.kv_lora_rank + m.qk_rope_head_dim, d_in),
+                ("kv up", H * (m.qk_nope_head_dim + m.v_head_dim),
+                 m.kv_lora_rank)]
+
+    def _moe_widths(self):
+        """(expert width, rows a token sends here, shared width) of an
+        expert layer: top_k assignments, of which held / n_experts land on
+        this chip's experts."""
+        mo = self.cfg.moe
+        ff = mo.d_expert or self.cfg.d_ff
+        shared = (self.cfg.d_ff if mo.shared_expert else 0) \
+            + mo.n_shared_experts * ff
+        return ff, mo.top_k * mo.held / mo.n_experts, shared
 
     def _mlp_fwd(self, prefix, inv=1, d_in=None):
         cfg = self.cfg
@@ -208,7 +246,7 @@ class WorkloadBuilder:
         E = cfg.moe.n_experts
         K = cfg.moe.top_k
         ep = min(self.tp, E)
-        ff = cfg.d_ff
+        ff, K_here, shared = self._moe_widths()
         n_up = 2 if cfg.activation == "swiglu" else 1
         self._gemm(f"{prefix}GEMM router", T, E, d, inv=inv)
         self._elem(f"{prefix}Softmax+topk", "softmax", T * E, rw=2, inv=inv,
@@ -219,7 +257,7 @@ class WorkloadBuilder:
             self._emit(f"{prefix}AllToAll dispatch", "alltoall", 0,
                        db * T * K * d / ep,
                        ici=db * T * K * d * (ep - 1) / ep, inv=inv)
-        Te = T * K / ep  # tokens per EP shard
+        Te = T * K_here / ep  # tokens per EP shard
         self._gemm(f"{prefix}GEMM experts up", Te, n_up * ff, d, inv=inv)
         self._elem(f"{prefix}{cfg.activation.upper()} experts", "gelu",
                    Te * ff, rw=2 + (n_up - 1), inv=inv, flops_per=6.0)
@@ -230,22 +268,23 @@ class WorkloadBuilder:
                        ici=db * T * K * d * (ep - 1) / ep, inv=inv)
         self._elem(f"{prefix}Combine gather", "dispatch", T * K * d / ep,
                    rw=2, inv=inv)
-        if cfg.moe.shared_expert:
-            self._gemm(f"{prefix}GEMM shared up", T, n_up * ff // self.tp,
-                       d, inv=inv)
-            self._elem(f"{prefix}Act shared", "gelu", T * ff // self.tp,
+        if shared:
+            self._gemm(f"{prefix}GEMM shared up", T,
+                       n_up * shared // self.tp, d, inv=inv)
+            self._elem(f"{prefix}Act shared", "gelu", T * shared // self.tp,
                        rw=2, inv=inv, flops_per=6.0)
-            self._gemm(f"{prefix}GEMM shared down", T, d, ff // self.tp,
-                       inv=inv)
+            self._gemm(f"{prefix}GEMM shared down", T, d,
+                       shared // self.tp, inv=inv)
 
     def _moe_bwd(self, prefix, inv=1):
         cfg = self.cfg
         T = self.B * self.S
         E, K = cfg.moe.n_experts, cfg.moe.top_k
         ep = min(self.tp, E)
-        ff, d = cfg.d_ff, cfg.d_model
+        d = cfg.d_model
+        ff, K_here, shared = self._moe_widths()
         n_up = 2 if cfg.activation == "swiglu" else 1
-        Te = T * K / ep
+        Te = T * K_here / ep
         self._elem(f"{prefix}Combine bwd", "dispatch", T * K * d / ep, rw=2,
                    inv=inv, phase="bwd")
         self._gemm_bwd(f"{prefix}GEMM experts down", Te, d, ff, inv=inv)
@@ -255,11 +294,11 @@ class WorkloadBuilder:
         self._elem(f"{prefix}Dispatch bwd", "dispatch", T * K * d / ep, rw=2,
                    inv=inv, phase="bwd")
         self._gemm_bwd(f"{prefix}GEMM router", T, E, d, inv=inv)
-        if cfg.moe.shared_expert:
+        if shared:
             self._gemm_bwd(f"{prefix}GEMM shared down", T, d,
-                           ff // self.tp, inv=inv)
+                           shared // self.tp, inv=inv)
             self._gemm_bwd(f"{prefix}GEMM shared up", T,
-                           n_up * ff // self.tp, d, inv=inv)
+                           n_up * shared // self.tp, d, inv=inv)
 
     def _norm(self, name, inv=1, phase="fwd", d=None):
         elems = self.B * self.S * (d or self.cfg.d_model)
@@ -379,6 +418,10 @@ class WorkloadBuilder:
             n_global = L // g
             layer_plans = [("local ", n_local, cfg.attn_window),
                            ("global ", n_global, 0)]
+        elif cfg.n_dense_layers:
+            # leading dense layers, then the expert layers
+            layer_plans = [("dense ", cfg.n_dense_layers, 0),
+                           ("", L - cfg.n_dense_layers, 0)]
         else:
             layer_plans = [("", L, 0)]
         for prefix, inv, window in layer_plans:
@@ -386,7 +429,7 @@ class WorkloadBuilder:
             self._attention_fwd(prefix, inv=inv, window=window)
             self._residual(f"{prefix}Residual attn", inv=inv)
             self._norm(f"{prefix}Layernorm mlp", inv=inv)
-            if cfg.is_moe:
+            if cfg.is_moe and prefix != "dense ":
                 self._moe_fwd(prefix, inv=inv)
             else:
                 self._mlp_fwd(prefix, inv=inv)
@@ -395,7 +438,7 @@ class WorkloadBuilder:
             for prefix, inv, window in layer_plans:
                 self._residual(f"{prefix}Residual mlp bwd", inv=inv,
                                phase="bwd")
-                if cfg.is_moe:
+                if cfg.is_moe and prefix != "dense ":
                     self._moe_bwd(prefix, inv=inv)
                 else:
                     self._mlp_bwd(prefix, inv=inv)
@@ -521,12 +564,15 @@ class WorkloadBuilder:
             ff = max(cfg.d_ff // self.tp, 1)
             n_up = 2 if cfg.activation == "swiglu" else 1
             if cfg.is_moe:
-                K = cfg.moe.top_k
-                gemv("GEMV router", cfg.moe.n_experts, d, inv=cfg.n_layers)
-                gemv("GEMV experts", K * (n_up + 1) * ff, d,
-                     inv=cfg.n_layers)
-                if cfg.moe.shared_expert:
-                    gemv("GEMV shared", (n_up + 1) * ff, d, inv=cfg.n_layers)
+                n_moe = cfg.n_layers - cfg.n_dense_layers
+                fe, K_here, shared = self._moe_widths()
+                gemv("GEMV router", cfg.moe.n_experts, d, inv=n_moe)
+                gemv("GEMV experts", K_here * (n_up + 1) * fe, d, inv=n_moe)
+                if shared:
+                    gemv("GEMV shared", (n_up + 1) * shared, d, inv=n_moe)
+                if cfg.n_dense_layers:
+                    gemv("GEMV mlp", (n_up + 1) * ff, d,
+                         inv=cfg.n_dense_layers)
             else:
                 gemv("GEMV mlp", (n_up + 1) * ff, d, inv=cfg.n_layers)
         elif cfg.family in ("encdec", "hybrid") and cfg.d_ff:

@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .kernel import (SUBTILE, causal_subtiles, count_subtiles, strip_mask,
-                     strips)
+                     strips, vmem_params)
 
 NEG_INF = -1e30
 
@@ -160,11 +160,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
                         block_q=128, block_k=128, kv_len=0,
                         interpret=False, _subtile=SUBTILE):
-    """q,o,do: (BH, Sq, D); k,v: (BH, Sk, D) (kv pre-repeated for GQA);
-    lse: (BH, Sq, 1).  Returns (dq, dk, dv).  ``_subtile`` is for the
-    tests only."""
+    """q: (BH, Sq, D); k: (BH, Sk, D); v: (BH, Sk, Dv); o, do: (BH, Sq,
+    Dv) (kv pre-repeated for GQA; Dv may differ from D, as in latent
+    attention); lse: (BH, Sq, 1).  Returns (dq, dk, dv).  ``_subtile`` is
+    for the tests only."""
     BH, Sq, D = q.shape
     _, Sk, _ = k.shape
+    Dv = v.shape[-1]
     nq = pl.cdiv(Sq, block_q)
     nk = pl.cdiv(Sk, block_k)
     scale = D ** -0.5
@@ -191,15 +193,16 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_map),
             pl.BlockSpec((1, block_k, D), kv_map),
-            pl.BlockSpec((1, block_k, D), kv_map),
-            pl.BlockSpec((1, block_q, D), q_map),
-            pl.BlockSpec((1, block_q, D), q_map),
+            pl.BlockSpec((1, block_k, Dv), kv_map),
+            pl.BlockSpec((1, block_q, Dv), q_map),
+            pl.BlockSpec((1, block_q, Dv), q_map),
             pl.BlockSpec((1, block_q, 1), q_map),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), q_map),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         name="flash_bwd_dq",
+        compiler_params=vmem_params(D, Dv),
         interpret=interpret,
     )(q, k, v, o, do, lse)
 
@@ -217,20 +220,21 @@ def flash_attention_bwd(q, k, v, o, do, lse, *, causal=True, window=0,
         in_specs=[
             pl.BlockSpec((1, block_q, D), qo_map),
             pl.BlockSpec((1, block_k, D), k_map),
-            pl.BlockSpec((1, block_k, D), k_map),
-            pl.BlockSpec((1, block_q, D), qo_map),
-            pl.BlockSpec((1, block_q, D), qo_map),
+            pl.BlockSpec((1, block_k, Dv), k_map),
+            pl.BlockSpec((1, block_q, Dv), qo_map),
+            pl.BlockSpec((1, block_q, Dv), qo_map),
             pl.BlockSpec((1, block_q, 1), qo_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), k_map),
-            pl.BlockSpec((1, block_k, D), k_map),
+            pl.BlockSpec((1, block_k, Dv), k_map),
         ],
         out_shape=[jax.ShapeDtypeStruct((BH, Sk, D), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, D), q.dtype)],
+                   jax.ShapeDtypeStruct((BH, Sk, Dv), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         name="flash_bwd_dkv",
+        compiler_params=vmem_params(D, Dv),
         interpret=interpret,
     )(q, k, v, o, do, lse)
     return dq, dk, dv

@@ -46,6 +46,16 @@ SUBTILE = 256
 SUBTILE_COUNTS = MetricsRegistry()
 
 
+def vmem_params(*widths: int):
+    """Compiler parameters of a flash kernel over heads of ``widths``.
+    Heads wider than 128 (latent attention's q/k of 192) at blocks of 1024
+    outgrow the default 16 MiB of scoped VMEM; a TPU v5e core has 128 MiB,
+    so such kernels may use 48 MiB.  Narrower heads keep the default."""
+    if max(widths) <= 128:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
+
+
 def causal_subtiles(block_q: int, block_k: int, c: int, causal: bool,
                     window: int, ragged: bool = False):
     """``(pairs, total)``: the (row, col) sub-tiles of edge ``min(c,
@@ -214,14 +224,17 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         block_k: int = 128, group: int = 1,
                         kv_len: int = 0, return_lse: bool = False,
                         interpret: bool = False, _subtile: int = SUBTILE):
-    """q: (BH, Sq, D); k, v: (BKV, Sk, D) with BH == BKV * group.
+    """q, k: (BH, Sq, D), (BKV, Sk, D); v: (BKV, Sk, Dv) with BH == BKV *
+    group.  Values may be narrower than queries and keys (latent attention:
+    D 192, Dv 128); scores are scaled by ``D ** -0.5``.
 
-    Returns (BH, Sq, D).  Sequences are padded to the block sizes by the
+    Returns (BH, Sq, Dv).  Sequences are padded to the block sizes by the
     ops wrapper; ``kv_len`` is the true (pre-padding) KV length so padded
     key columns are masked out.  ``_subtile`` is for the tests only.
     """
     BH, Sq, D = q.shape
     BKV, Sk, _ = k.shape
+    Dv = v.shape[-1]
     assert BH == BKV * group
     nq = pl.cdiv(Sq, block_q)
     nk = pl.cdiv(Sk, block_k)
@@ -238,17 +251,17 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
     if return_lse:
         kernel = body
         out_specs = [
-            pl.BlockSpec((1, block_q, D), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ]
-        out_shape = [jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+        out_shape = [jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
                      jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32)]
     else:
         def kernel(q_ref, k_ref, v_ref, o_ref, *scratch):
             body(q_ref, k_ref, v_ref, o_ref, None, *scratch)
-        out_specs = pl.BlockSpec((1, block_q, D),
+        out_specs = pl.BlockSpec((1, block_q, Dv),
                                  lambda bh, iq, ik: (bh, iq, 0))
-        out_shape = jax.ShapeDtypeStruct((BH, Sq, D), q.dtype)
+        out_shape = jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype)
 
     def kv_map(bh, iq, ik):
         if pairs is not None:
@@ -261,15 +274,16 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, block_k, D), kv_map),
-            pl.BlockSpec((1, block_k, D), kv_map),
+            pl.BlockSpec((1, block_k, Dv), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, D), jnp.float32),   # accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # accumulator
         ],
         name="flash_fwd",
+        compiler_params=vmem_params(D, Dv),
         interpret=interpret,
     )(q, k, v)

@@ -54,7 +54,9 @@ def flash_attention_train(q, k, v, causal: bool = True, window: int = 0,
                           interpret: bool = False):
     """Differentiable flash attention (no softcap; GQA via kv repeat).
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KV, D).  The backward pass recomputes
+    q, k: (B, Sq, H, D), (B, Sk, KV, D); v: (B, Sk, KV, Dv), where Dv may
+    be narrower than D (latent attention); returns (B, Sq, H, Dv).  The
+    backward pass recomputes
     tile probabilities from the saved (o, lse) — the flash-bwd recipe.
     """
     o, _ = _fa_train_fwd(q, k, v, causal, window, block_q, block_k,
@@ -71,14 +73,14 @@ def _fa_layout(q, k, v):
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, D)
-    return qf, kf, vf, (B, Sq, Sk, H, KV, D, G)
+    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Sk, v.shape[-1])
+    return qf, kf, vf, (B, Sq, Sk, H, KV, v.shape[-1], G)
 
 
 def _fa_train_fwd(q, k, v, causal, window, block_q, block_k, interpret):
     from .kernel import flash_attention_fwd
     qf, kf, vf, dims = _fa_layout(q, k, v)
-    B, Sq, Sk, H, KV, D, G = dims
+    B, Sq, Sk, H, KV, Dv, G = dims
     bq, bk = min(block_q, Sq), min(block_k, Sk)
     pq, pk = (-Sq) % bq, (-Sk) % bk
     qp = jnp.pad(qf, ((0, 0), (0, pq), (0, 0))) if pq else qf
@@ -90,26 +92,27 @@ def _fa_train_fwd(q, k, v, causal, window, block_q, block_k, interpret):
                                  interpret=interpret)
     res = (qp, kp, vp, o, lse, dims)
     out = o[:, :Sq] if pq else o
-    return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3), res
+    return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3), res
 
 
 def _fa_train_bwd(causal, window, block_q, block_k, interpret, res, g):
     from .backward import flash_attention_bwd
     qp, kp, vp, o, lse, dims = res
-    B, Sq, Sk, H, KV, D, G = dims
+    B, Sq, Sk, H, KV, Dv, G = dims
+    D = qp.shape[-1]
     bq, bk = min(block_q, Sq), min(block_k, Sk)
     pq = (-Sq) % bq
-    gf = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
+    gf = g.transpose(0, 2, 1, 3).reshape(B * H, Sq, Dv)
     gp = jnp.pad(gf, ((0, 0), (0, pq), (0, 0))) if pq else gf
     dq, dk, dv = flash_attention_bwd(
         qp, kp, vp, o, gp, lse, causal=causal, window=window,
         block_q=bq, block_k=bk, kv_len=Sk, interpret=interpret)
     dq = dq[:, :Sq].reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     dk = dk[:, :Sk].reshape(B, H, Sk, D).transpose(0, 2, 1, 3)
-    dv = dv[:, :Sk].reshape(B, H, Sk, D).transpose(0, 2, 1, 3)
+    dv = dv[:, :Sk].reshape(B, H, Sk, Dv).transpose(0, 2, 1, 3)
     if G > 1:  # sum gradients over the repeated query groups
         dk = dk.reshape(B, Sk, KV, G, D).sum(axis=3)
-        dv = dv.reshape(B, Sk, KV, G, D).sum(axis=3)
+        dv = dv.reshape(B, Sk, KV, G, Dv).sum(axis=3)
     return dq, dk, dv
 
 

@@ -217,15 +217,17 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       kv_valid_len: Optional[jnp.ndarray] = None):
     """Memory-efficient attention: lax.scan over key blocks, online softmax.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KV, D) with H % KV == 0.
+    q: (B, Sq, H, D); k: (B, Sk, KV, D); v: (B, Sk, KV, Dv) with H % KV
+    == 0 (Dv may differ from D, as in latent attention).
     ``q_offset`` is the absolute position of q[0] (prefill continuation).
     ``kv_valid_len`` optionally masks out cache positions >= valid_len.
-    Returns (B, Sq, H, D).
+    Returns (B, Sq, H, Dv).
 
     This doubles as the oracle for the Pallas flash-attention kernel.
     """
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
+    Dv = v.shape[-1]
     G = H // KV
     # TP plan: shard heads over the model axis when divisible; otherwise
     # fall back to *sequence* sharding of Q (context parallelism) — k/v
@@ -258,7 +260,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_blocks = (Sk + pad) // bk
     kb = k.reshape(B, n_blocks, bk, KV, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, n_blocks, bk, KV, D).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(B, n_blocks, bk, KV, Dv).transpose(1, 0, 2, 3, 4)
 
     def step(carry, inputs):
         m_prev, l_prev, acc = carry
@@ -293,11 +295,11 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
-    acc0 = jnp.zeros((B, Sq, KV, G, D), jnp.float32)
+    acc0 = jnp.zeros((B, Sq, KV, G, Dv), jnp.float32)
     (m_f, l_f, acc_f), _ = lax.scan(
         step, (m0, l0, acc0), (kb, vb, jnp.arange(n_blocks)))
     l_f = jnp.maximum(l_f, 1e-20).transpose(0, 3, 1, 2)[..., None]
-    out = (acc_f / l_f).reshape(B, Sq, H, D)
+    out = (acc_f / l_f).reshape(B, Sq, H, Dv)
     return out.astype(q.dtype)
 
 
@@ -342,8 +344,8 @@ def init_attention(b: ParamBuilder, d_in: int, n_heads: int, n_kv: int,
 
 
 def _attention_kernels() -> Optional[Dict[str, bool]]:
-    """Keyword arguments for the Pallas attention kernels, or None for the
-    ``jnp`` path.  The compiled kernels run on a TPU backend; every other
+    """Keyword arguments for the Pallas attention kernels (and the grouped
+    matmul of the expert layers), or None for the ``jnp`` path.  The compiled kernels run on a TPU backend; every other
     platform takes the ``jnp`` path.  Read while tracing, never at import,
     so the choice follows the backend the program is traced for."""
     return {"interpret": False} if jax.default_backend() == "tpu" else None
@@ -408,6 +410,75 @@ def attention_block(p: Params, x: jnp.ndarray, *, cfg_theta: float,
     if return_kv:
         return out, (k, v)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+
+def init_mla(b: ParamBuilder, d: int, n_heads: int, mla) -> Params:
+    """Weights of a latent-attention block (``mla``: a ``MLAConfig``):
+    queries straight from x, keys and values from one normalized latent."""
+    H, r = n_heads, mla.kv_lora_rank
+    nope, rope, dv = mla.qk_nope_head_dim, mla.qk_rope_head_dim, \
+        mla.v_head_dim
+    return {
+        "wq": b.param((d, H, nope + rope), ("embed", "heads", None)),
+        "w_kva": b.param((d, r + rope), ("embed", None)),
+        "kv_norm": init_norm(b, r, "rms"),
+        "w_kvb": b.param((r, H, nope + dv), (None, "heads", None)),
+        "wo": b.param((H, dv, d), ("heads", None, "embed"),
+                      scale=(H * dv) ** -0.5),
+    }
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           theta: float) -> jnp.ndarray:
+    """RoPE on adjacent pairs ``(x[2i], x[2i+1])`` at frequency ``i``,
+    returned with the evens first and the odds after them, as DeepSeek-V3's
+    ``rope_interleave`` layout does.  x: (..., S, H, D)."""
+    d = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2).reshape(
+        x.shape)
+    return apply_rope(x, positions, theta)
+
+
+@scope("attn")
+def mla_attention_block(p: Params, x: jnp.ndarray, *, n_heads: int, mla,
+                        theta: float, block_k: int = 1024) -> jnp.ndarray:
+    """Causal latent attention over x: (B, S, d).
+
+    ``q = x W_q`` (heads of nope + rope); ``[c, k_r] = x W_kva``, ``c``
+    RMS-normalized; ``[k_nope, v] = c W_kvb``; RoPE on ``q_rope`` and
+    ``k_r``, one ``k_r`` shared by every head; attention with q/k of
+    nope + rope and v of ``v_head_dim``, scaled by (nope + rope) ** -0.5.
+    """
+    B, S, _ = x.shape
+    r, nope, rope = mla.kv_lora_rank, mla.qk_nope_head_dim, \
+        mla.qk_rope_head_dim
+    dt = x.dtype
+    pos = jnp.arange(S)
+    q = jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], dt))
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope_interleaved(q[..., nope:], pos, theta)],
+        axis=-1)
+    kva = jnp.einsum("bsd,dr->bsr", x, cast(p["w_kva"], dt))
+    c = apply_norm(p["kv_norm"], kva[..., :r], "rms")
+    kv = jnp.einsum("bsr,rhk->bshk", c, cast(p["w_kvb"], dt))
+    k_r = apply_rope_interleaved(kva[..., None, r:], pos, theta)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_r, (B, S, n_heads, rope))],
+        axis=-1)
+    v = kv[..., nope:]
+    kernels = _attention_kernels()
+    if kernels is not None:
+        from ..kernels.flash_attention import flash_attention_train
+        blk = min(block_k, S)
+        o = _per_device(lambda q, k, v: flash_attention_train(
+            q, k, v, True, 0, blk, blk, kernels["interpret"]), q, k, v)
+    else:
+        o = chunked_attention(q, k, v, causal=True, block_k=block_k)
+    return jnp.einsum("bshk,hkd->bsd", o, cast(p["wo"], dt))
 
 
 # ---------------------------------------------------------------------------
@@ -558,6 +629,146 @@ def apply_moe(p: Params, x: jnp.ndarray, *, n_experts: int, top_k: int,
             logits, axis=-1))),
         "dropped_frac": 1.0 - keep.mean(),
     }
+    return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless routed experts, of which this chip holds a share (DeepSeek-V3)
+# ---------------------------------------------------------------------------
+
+def init_routed_moe(b: ParamBuilder, d: int, d_ff: int, moe) -> Params:
+    """Router over all ``moe.n_experts``, the selection bias (not trained),
+    the ``moe.held`` experts this chip holds and the shared SwiGLU."""
+    f = moe.d_expert or d_ff
+    n = moe.held
+    p = {"router": b.param((d, moe.n_experts), ("embed", None))}
+    if moe.bias_rate:
+        p["select_bias"] = b.param((moe.n_experts,), (None,), init="zeros")
+    p["w_gate"] = b.param((n, d, f), ("experts", "embed", "mlp"),
+                          scale=d ** -0.5)
+    p["w_up"] = b.param((n, d, f), ("experts", "embed", "mlp"),
+                        scale=d ** -0.5)
+    p["w_down"] = b.param((n, f, d), ("experts", "mlp", "embed"),
+                          scale=f ** -0.5)
+    if moe.n_shared_experts:
+        p["shared"] = init_mlp(b, d, moe.n_shared_experts * f, "swiglu")
+    return p
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def permute_rows(x: jnp.ndarray, idx: jnp.ndarray, inv: jnp.ndarray,
+                 k: int) -> jnp.ndarray:
+    """``x[idx // k]``: each row of ``x`` taken ``k`` times, in the order of
+    ``idx``, a permutation of ``len(x) * k`` with inverse ``inv``.  Its
+    gradient gathers by ``inv`` and sums each row's ``k`` copies, where a
+    plain gather's gradient is a scatter-add, which a TPU runs far
+    slower."""
+    return jnp.take(x, idx // k, axis=0)
+
+
+def _permute_rows_fwd(x, idx, inv, k):
+    return permute_rows(x, idx, inv, k), (idx, inv)
+
+
+def _permute_rows_bwd(k, res, g):
+    idx, inv = res
+    g = jnp.take(g, inv, axis=0)
+    g = g.reshape((g.shape[0] // k, k) + g.shape[1:])
+    return g.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """Tiles of the Pallas grouped matmul: 512 rows (fewer where ``m`` is
+    smaller), k up to 2048 and n up to 512.  On a TPU v5e at
+    train-moonlight-ep8's shapes this was the fastest of six tilings, and
+    42% faster forward and backward than ``lax.ragged_dot`` (PERF.md §6)."""
+    return min(m, 512), min(k, 2048), min(n, 512)
+
+
+def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
+                   sizes: jnp.ndarray) -> jnp.ndarray:
+    """``rows`` (M, k) sorted by group: the first ``sizes[0]`` rows through
+    ``w[0]`` (G, k, n), the next ``sizes[1]`` through ``w[1]``, and so on;
+    the rows after the last group read zero and cost nothing.  On a TPU the
+    Pallas grouped matmul (megablox ``gmm``; its kernels keep the named
+    scope they run in), elsewhere ``lax.ragged_dot``."""
+    kernels = _attention_kernels()
+    if kernels is None:
+        return lax.ragged_dot(rows, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    # the rows after the held groups form one more group, of no weight
+    # here: the kernel visits only the groups of ``w`` and zeroes the rest
+    rest = rows.shape[0] - sizes.sum()
+    return gmm(rows, w, jnp.concatenate([sizes, rest[None]]), rows.dtype,
+               _gmm_tiling, interpret=kernels["interpret"])
+
+
+@scope("mlp")
+def apply_routed_moe(p: Params, x: jnp.ndarray, *, n_experts: int,
+                     top_k: int, routed_scale: float, first: int = 0
+                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Dropless top-k routing over all ``n_experts``; this chip computes the
+    part of the result that its experts ``first .. first + held - 1`` give,
+    plus the shared expert.  x: (B, S, d).
+
+    Scores ``s`` are ``sigmoid(x W_r)`` in float32; the top
+    ``top_k`` of ``s + select_bias`` are chosen, weighted by ``s`` over
+    their sum times ``routed_scale``.  The token-expert assignments held
+    here are sorted by expert and run as grouped matmuls; no token is
+    dropped.  Returns (y, aux): the sequence-wise balance loss over all
+    experts (DeepSeek-V3 eqs. 17-20, without its weight), each expert's
+    load (assignments of these tokens), the rows computed here and the
+    largest held expert's rows.
+    """
+    B, S, d = x.shape
+    T, K = B * S, top_k
+    n = p["w_gate"].shape[0]
+    dt = x.dtype
+    xt = x.reshape(T, d)
+    with scope("router"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        s = jax.nn.sigmoid(logits)
+        choice = s + p["select_bias"] if "select_bias" in p else s
+        _, top_e = lax.top_k(lax.stop_gradient(choice), K)    # (T, K)
+        top_w = jnp.take_along_axis(s, top_e, axis=1)
+        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20) \
+            * routed_scale
+    with scope("dispatch"):
+        local = top_e.reshape(T * K) - first
+        group = jnp.where((local >= 0) & (local < n), local, n)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=n + 1)[:n]
+        back = jnp.zeros((T * K,), order.dtype).at[order].set(
+            jnp.arange(T * K, dtype=order.dtype))
+        rows = permute_rows(xt, order, back, K)
+    with scope("experts"):
+        g = grouped_matmul(rows, cast(p["w_gate"], dt), sizes)
+        u = grouped_matmul(rows, cast(p["w_up"], dt), sizes)
+        y_rows = grouped_matmul(jax.nn.silu(g) * u, cast(p["w_down"], dt),
+                                sizes)
+    with scope("dispatch"):
+        # back to token order: the rows of experts held elsewhere are 0
+        y = permute_rows(y_rows, back, order, 1).reshape(T, K, d)
+        y = (y.astype(jnp.float32) * top_w[..., None]).sum(axis=1)
+        y = y.astype(dt).reshape(B, S, d)
+    if "shared" in p:
+        with scope("shared"):
+            y = y + apply_mlp(p["shared"], x, "swiglu")
+    with scope("router"):
+        chosen = jax.nn.one_hot(top_e, n_experts, dtype=jnp.float32).sum(1)
+        load = chosen.sum(0)                                   # (E,)
+        # per sequence: f_i = E / (K S) * picks of i, P_i = mean of the
+        # scores normalized over all experts
+        f = chosen.reshape(B, S, n_experts).sum(1) * n_experts / (K * S)
+        pr = (s / s.sum(-1, keepdims=True)).reshape(B, S, n_experts)
+        balance = jnp.mean(jnp.sum(f * pr.mean(1), axis=-1))
+    aux = {"balance": balance, "expert_load": load,
+           "routed_rows_here": sizes.sum().astype(jnp.float32),
+           "max_expert_load": sizes.max().astype(jnp.float32)}
     return y, aux
 
 

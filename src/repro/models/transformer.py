@@ -56,7 +56,10 @@ class DecoderLM:
             assert cfg.n_layers % self.group == 0, cfg.name
         else:
             self.group = 1
-        self.n_groups = cfg.n_layers // self.group
+        # leading dense layers run before the scan, outside it
+        self.n_groups = (cfg.n_layers - cfg.n_dense_layers) // self.group
+        # dropless routed experts (apply_routed_moe) in the scanned layers
+        self.routed = cfg.is_moe and cfg.moe.dropless
         self.compute_dtype = jnp.dtype(cfg.compute_dtype)
         self.param_dtype = jnp.dtype(cfg.param_dtype)
 
@@ -70,15 +73,19 @@ class DecoderLM:
         return 0 if is_global else cfg.attn_window
 
     # -- params ----------------------------------------------------------
-    def _init_layer(self, b: ParamBuilder) -> Params:
+    def _init_layer(self, b: ParamBuilder, dense: bool = False) -> Params:
         cfg = self.cfg
         p: Params = {
             "norm_attn": cm.init_norm(b, cfg.d_model, cfg.norm),
-            "attn": cm.init_attention(b, cfg.d_model, cfg.n_heads,
-                                      cfg.n_kv_heads, self.head_dim),
+            "attn": (cm.init_mla(b, cfg.d_model, cfg.n_heads, cfg.mla)
+                     if cfg.mla.enabled else
+                     cm.init_attention(b, cfg.d_model, cfg.n_heads,
+                                       cfg.n_kv_heads, self.head_dim)),
             "norm_mlp": cm.init_norm(b, cfg.d_model, cfg.norm),
         }
-        if cfg.is_moe:
+        if self.routed and not dense:
+            p["moe"] = cm.init_routed_moe(b, cfg.d_model, cfg.d_ff, cfg.moe)
+        elif cfg.is_moe and not dense:
             p["moe"] = cm.init_moe(b, cfg.d_model, cfg.d_ff,
                                    cfg.moe.n_experts, cfg.activation,
                                    cfg.moe.shared_expert)
@@ -96,14 +103,43 @@ class DecoderLM:
                 learned_pos=(cfg.positional == "learned")),
             "final_norm": cm.init_norm(b, cfg.d_model, cfg.norm),
         }
-        if mode == ParamBuilder.INIT:
-            layers = [self._init_layer(b) for _ in range(cfg.n_layers)]
-            params["layers"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *layers)
-        else:
-            one = self._init_layer(b)
-            params["layers"] = _stack_tree(one, cfg.n_layers, mode)
+        n_scan = cfg.n_layers - cfg.n_dense_layers
+        for key, n, dense in (("dense_layers", cfg.n_dense_layers, True),
+                              ("layers", n_scan, False)):
+            if not n:
+                continue
+            if mode == ParamBuilder.INIT:
+                layers = [self._init_layer(b, dense) for _ in range(n)]
+                params[key] = jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *layers)
+            else:
+                params[key] = _stack_tree(self._init_layer(b, dense), n,
+                                          mode)
         return params
+
+    def frozen_mask(self):
+        """Per parameter, True for state that the optimizer leaves alone
+        (the router's selection bias, moved by :meth:`update_frozen`);
+        None when the model has no such state."""
+        if not (self.routed and self.cfg.moe.bias_rate):
+            return None
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: path[-1].key == "select_bias",
+            self.abstract_params())
+
+    def update_frozen(self, params: Params, metrics: Dict) -> Params:
+        """The selection bias after a step: each expert's moves by
+        ``bias_rate`` toward the mean load, ``b += rate * sign(mean - load)``
+        (DeepSeek-V3), with the load the step's assignments on this chip."""
+        if self.frozen_mask() is None:
+            return params
+        load = metrics["expert_load"]                      # (layers, E)
+        step = self.cfg.moe.bias_rate * jnp.sign(
+            load.mean(-1, keepdims=True) - load)
+        moe = dict(params["layers"]["moe"])
+        moe["select_bias"] = moe["select_bias"] + step.astype(
+            moe["select_bias"].dtype)
+        return {**params, "layers": {**params["layers"], "moe": moe}}
 
     def init(self, rng) -> Params:
         return self._build(ParamBuilder.INIT, rng)
@@ -120,14 +156,26 @@ class DecoderLM:
         cfg = self.cfg
         window = self._layer_window(idx_in_group)
         h = cm.apply_norm(lp["norm_attn"], x, cfg.norm)
-        h = cm.attention_block(
-            lp["attn"], h, cfg_theta=cfg.rope_theta,
-            positional=cfg.positional, causal=True, window=window,
-            softcap=cfg.attn_logit_softcap, q_offset=q_offset,
-            block_k=self.block_k)
+        if cfg.mla.enabled:
+            h = cm.mla_attention_block(
+                lp["attn"], h, n_heads=cfg.n_heads, mla=cfg.mla,
+                theta=cfg.rope_theta, block_k=self.block_k)
+        else:
+            h = cm.attention_block(
+                lp["attn"], h, cfg_theta=cfg.rope_theta,
+                positional=cfg.positional, causal=True, window=window,
+                softcap=cfg.attn_logit_softcap, q_offset=q_offset,
+                block_k=self.block_k)
         x = x + h
         h = cm.apply_norm(lp["norm_mlp"], x, cfg.norm)
-        if cfg.is_moe:
+        if "mlp" in lp:
+            h = cm.apply_mlp(lp["mlp"], h, cfg.activation)
+        elif self.routed:
+            h, aux = cm.apply_routed_moe(
+                lp["moe"], h, n_experts=cfg.moe.n_experts,
+                top_k=cfg.moe.top_k, routed_scale=cfg.moe.routed_scale)
+            aux_acc.update(aux)
+        elif cfg.is_moe:
             h, aux = cm.apply_moe(
                 lp["moe"], h, n_experts=cfg.moe.n_experts,
                 top_k=cfg.moe.top_k,
@@ -136,8 +184,6 @@ class DecoderLM:
                 shared_expert=cfg.moe.shared_expert)
             for k, v in aux.items():
                 aux_acc[k] = aux_acc.get(k, 0.0) + v
-        else:
-            h = cm.apply_mlp(lp["mlp"], h, cfg.activation)
         return x + h, aux_acc
 
     def forward_hidden(self, params: Params, x: jnp.ndarray,
@@ -145,6 +191,13 @@ class DecoderLM:
                        ) -> Tuple[jnp.ndarray, Dict]:
         """Run the layer stack on embedded input x: (B, S, d)."""
         cfg = self.cfg
+        for i in range(cfg.n_dense_layers):
+            dense = functools.partial(self._layer_fwd, idx_in_group=0,
+                                      q_offset=q_offset, aux_acc={})
+            if remat:
+                dense = jax.checkpoint(dense, prevent_cse=False)
+            x, _ = dense(jax.tree.map(lambda a, i=i: a[i],
+                                      params["dense_layers"]), x)
         glayers = jax.tree.map(
             lambda a: a.reshape((self.n_groups, self.group) + a.shape[1:]),
             params["layers"])
@@ -154,6 +207,8 @@ class DecoderLM:
             for i in range(self.group):
                 lp = jax.tree.map(lambda a, i=i: a[i], gp)
                 x, aux = self._layer_fwd(lp, x, i, q_offset, aux)
+            if self.routed:
+                return x, aux
             if _SP_RESIDUAL:
                 # sequence-parallel residual stream: the scan carry (and
                 # the per-layer saved residuals under remat) shard over
@@ -170,7 +225,12 @@ class DecoderLM:
                                   prevent_cse=False)
         x, aux_stack = lax.scan(body, x, glayers)
         aux = {}
-        if cfg.is_moe:
+        if self.routed:
+            # the balance loss and row counts as means over the MoE
+            # layers; each layer's expert loads kept for the bias rule
+            aux = {k: (v if k == "expert_load" else v.mean())
+                   for k, v in aux_stack.items()}
+        elif cfg.is_moe:
             s = aux_stack.sum(axis=0)
             aux = {"load_balance": s[0] / cfg.n_layers,
                    "router_z": s[1] / cfg.n_layers,
@@ -205,7 +265,10 @@ class DecoderLM:
             loss = cm.softmax_cross_entropy(logits, targets, mask,
                                             z_loss=1e-4)
         metrics = {"ce_loss": loss}
-        if cfg.is_moe:
+        if self.routed:
+            loss = loss + cfg.moe.aux_loss_weight * aux["balance"]
+            metrics.update(aux)
+        elif cfg.is_moe:
             loss = (loss + cfg.moe.aux_loss_weight * aux["load_balance"]
                     + cfg.moe.router_z_loss_weight * aux["router_z"])
             metrics.update(aux)
@@ -216,6 +279,11 @@ class DecoderLM:
     def _cache_struct(self, B: int, max_seq: int):
         """Abstract KV-cache tree (grouped; ring buffers for local layers)."""
         cfg = self.cfg
+        if cfg.mla.enabled or cfg.n_dense_layers or self.routed:
+            raise NotImplementedError(
+                f"{cfg.name}: serving latent attention, leading dense "
+                f"layers and routed experts is not implemented; the model "
+                f"trains only")
         KV, D = cfg.n_kv_heads, self.head_dim
         dt = self.compute_dtype
 

@@ -10,7 +10,8 @@
 * :mod:`repro.obs.ledger` — the three-tier energy-conservation ledger
   and the ``check_*`` reconciliation functions;
 * :mod:`repro.obs.scopes` — the vocabulary of ``jax.named_scope`` names
-  on the training path (:data:`SCOPES`) and :func:`scope`.
+  on the training path (:data:`SCOPES`, and :data:`MOE_SCOPES` inside an
+  expert layer) and :func:`scope`.
 
 Imports only :mod:`repro.core` (+ stdlib / numpy / jax), so every other
 subpackage may depend on it without cycles.
@@ -24,7 +25,7 @@ from .schema import (CATEGORIES, KINDS, OBS_SCHEMA_VERSION,
                      from_recovery_books, from_replica_events,
                      ingest_legacy_streams, make_event,
                      validate_trace_dict)
-from .scopes import SCOPES, scope
+from .scopes import MOE_SCOPES, SCOPES, scope
 from .tracer import NULL_TRACER, NullTracer, Tracer
 
 __all__ = [
@@ -36,5 +37,5 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "EnergyLedger", "segment_breakdown", "executor_ledger",
     "replica_ledger", "fleet_ledger", "check_executor",
-    "check_replica", "check_fleet", "SCOPES", "scope",
+    "check_replica", "check_fleet", "SCOPES", "MOE_SCOPES", "scope",
 ]

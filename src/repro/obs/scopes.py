@@ -15,11 +15,17 @@ import jax
 
 SCOPES = ("embed", "attn", "mlp", "norm", "head", "grad_accum", "opt")
 
+#: The parts of a dropless expert layer (``models/common.py
+#: apply_routed_moe``), nested inside its ``mlp`` scope: scores, bias, top-k
+#: and weights; sort, permute, gather and the weighted combine; the grouped
+#: matmuls; the shared expert.
+MOE_SCOPES = ("router", "dispatch", "experts", "shared")
+
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for a name of :data:`SCOPES`; usable as a
-    context manager or a decorator."""
-    if name not in SCOPES:
-        raise ValueError(f"unknown scope {name!r}; the vocabulary is "
-                         f"{SCOPES}")
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES` or
+    :data:`MOE_SCOPES`; usable as a context manager or a decorator."""
+    if name not in SCOPES + MOE_SCOPES:
+        raise ValueError(f"unknown scope {name!r}; the vocabularies are "
+                         f"{SCOPES} and {MOE_SCOPES}")
     return jax.named_scope(name)

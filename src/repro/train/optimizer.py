@@ -59,8 +59,15 @@ def init_opt_state(params) -> Dict:
             "step": jnp.zeros((), jnp.int32)}
 
 
-def adamw_update(params, grads, state, cfg: OptimizerConfig):
-    """One AdamW step.  Returns (new_params, new_state, metrics)."""
+def adamw_update(params, grads, state, cfg: OptimizerConfig, frozen=None):
+    """One AdamW step.  Returns (new_params, new_state, metrics).
+
+    ``frozen``, a tree of bools beside ``params``, marks state that is not
+    trained (a router's selection bias): it keeps its value and moments
+    and is left out of the clipping norm."""
+    if frozen is not None:
+        grads = jax.tree.map(lambda g, f: jnp.zeros_like(g) if f else g,
+                             grads, frozen)
     grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip_norm)
     step = state["step"] + 1
     lr = lr_at(cfg, step)
@@ -82,9 +89,11 @@ def adamw_update(params, grads, state, cfg: OptimizerConfig):
     flat_g = jax.tree.leaves(grads)
     flat_m = jax.tree.leaves(state["m"])
     flat_v = jax.tree.leaves(state["v"])
+    flat_f = jax.tree.leaves(frozen) if frozen is not None \
+        else [False] * len(flat_p)
     new_p, new_m, new_v = [], [], []
-    for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v):
-        np_, nm, nv = upd(p, g, m, v)
+    for p, g, m, v, f in zip(flat_p, flat_g, flat_m, flat_v, flat_f):
+        np_, nm, nv = (p, m, v) if f else upd(p, g, m, v)
         new_p.append(np_)
         new_m.append(nm)
         new_v.append(nv)

@@ -77,6 +77,8 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    # state the optimizer leaves alone, moved by the model's own rule
+    frozen = getattr(model, "frozen_mask", lambda: None)()
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         rng, step_rng = jax.random.split(state.rng)
@@ -104,7 +106,7 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
             (grads, _), (losses, metricses) = lax.scan(
                 body, (acc0, step_rng), micro)
             loss = losses.mean()
-            metrics = jax.tree.map(lambda m: m.mean(), metricses)
+            metrics = jax.tree.map(lambda m: m.mean(axis=0), metricses)
 
         if compress:
             crng = jax.random.fold_in(rng, 1)
@@ -113,7 +115,9 @@ def make_train_step(model, opt_cfg: OptimizerConfig,
 
         with scope("opt"):
             params, opt, opt_metrics = adamw_update(
-                state.params, grads, state.opt, opt_cfg)
+                state.params, grads, state.opt, opt_cfg, frozen)
+        if frozen is not None:
+            params = model.update_frozen(params, metrics)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss

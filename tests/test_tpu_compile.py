@@ -109,3 +109,35 @@ def test_flash_attention_train_compiles(one_chip, backward):
             flash_bwd.cost(B * H, S, D)
         assert flash_bwd.call_cost(calls["flash_bwd_dkv"]) == (0.0, 0.0)
         assert flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_mla_flash_train_compiles(one_chip, backward):
+    """The flash kernels at train-moonlight-ep8's shapes: one row of 8192
+    tokens, 16 heads, q/k of 192 and v of 128, blocks of 1024 (an 8 x 8
+    grid with the causal sub-tile plan).  They keep their three names and
+    the benchmark's latent-attention readers find them by their shapes."""
+    B, S, H, Dqk, Dv, block = 1, 8192, 16, 192, 128, 1024
+    sds = lambda d: jax.ShapeDtypeStruct((B, S, H, d), jnp.bfloat16,
+                                         sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention_train(q, k, v, True, 0, block, block, False)
+
+    fn = fwd
+    if backward:
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    from bench.kernels import flash_fwd, mla_flash_bwd, mla_flash_fwd
+    calls = _flash_calls(_compiled_text(fn, sds(Dqk), sds(Dqk), sds(Dv)))
+    names = ["flash_fwd"] + (["flash_bwd_dq", "flash_bwd_dkv"]
+                             if backward else [])
+    assert sorted(calls) == sorted(names)
+    assert mla_flash_fwd.call_cost(calls["flash_fwd"]) == \
+        mla_flash_fwd.cost(B * H, S, Dqk, Dv)
+    if backward:
+        assert mla_flash_bwd.call_cost(calls["flash_bwd_dq"]) == \
+            mla_flash_bwd.cost(B * H, S, Dqk, Dv)
+        assert mla_flash_bwd.call_cost(calls["flash_bwd_dkv"]) == (0.0, 0.0)
+        assert mla_flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
+        assert flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
