@@ -655,29 +655,9 @@ def init_routed_moe(b: ParamBuilder, d: int, d_ff: int, moe) -> Params:
     return p
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def permute_rows(x: jnp.ndarray, idx: jnp.ndarray, inv: jnp.ndarray,
-                 k: int) -> jnp.ndarray:
-    """``x[idx // k]``: each row of ``x`` taken ``k`` times, in the order of
-    ``idx``, a permutation of ``len(x) * k`` with inverse ``inv``.  Its
-    gradient gathers by ``inv`` and sums each row's ``k`` copies, where a
-    plain gather's gradient is a scatter-add, which a TPU runs far
-    slower."""
-    return jnp.take(x, idx // k, axis=0)
-
-
-def _permute_rows_fwd(x, idx, inv, k):
-    return permute_rows(x, idx, inv, k), (idx, inv)
-
-
-def _permute_rows_bwd(k, res, g):
-    idx, inv = res
-    g = jnp.take(g, inv, axis=0)
-    g = g.reshape((g.shape[0] // k, k) + g.shape[1:])
-    return g.astype(jnp.float32).sum(1).astype(g.dtype), None, None
-
-
-permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+# rows of the Pallas grouped matmul's tile, and the unit of the capped
+# routed buffer (``routed_cap``)
+_GMM_ROWS = 512
 
 
 def _gmm_tiling(m: int, k: int, n: int):
@@ -685,7 +665,7 @@ def _gmm_tiling(m: int, k: int, n: int):
     smaller), k up to 2048 and n up to 512.  On a TPU v5e at
     train-moonlight-ep8's shapes this was the fastest of six tilings, and
     42% faster forward and backward than ``lax.ragged_dot`` (PERF.md §6)."""
-    return min(m, 512), min(k, 2048), min(n, 512)
+    return min(m, _GMM_ROWS), min(k, 2048), min(n, 512)
 
 
 def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
@@ -706,6 +686,147 @@ def grouped_matmul(rows: jnp.ndarray, w: jnp.ndarray,
                _gmm_tiling, interpret=kernels["interpret"])
 
 
+def routed_cap(tokens: int, top_k: int, held: int,
+               n_experts: int) -> Optional[int]:
+    """Rows of the capped buffer of an expert layer whose chip holds
+    ``held`` of ``n_experts`` experts, or None where no cap applies (the
+    buffer then holds all ``tokens * top_k`` copies)."""
+    # twice the rows the held experts expect from an even draw, in whole
+    # row tiles of the grouped matmul: at train-moonlight-ep8's shapes
+    # 12,288 of the 49,152 copies, where the worst uniform draw on record
+    # (6,288 rows) leaves 1.95x headroom and the selection bias keeps the
+    # loads near even in training.  A chip holding half the experts or
+    # more keeps the buffer of all copies.
+    expected = -(-2 * tokens * top_k * held // n_experts)
+    cap = -(-expected // _GMM_ROWS) * _GMM_ROWS
+    return cap if cap < tokens * top_k else None
+
+
+def _experts_swiglu(rows, w_gate, w_up, w_down, sizes):
+    g = grouped_matmul(rows, w_gate, sizes)
+    u = grouped_matmul(rows, w_up, sizes)
+    return grouped_matmul(jax.nn.silu(g) * u, w_down, sizes)
+
+
+def _to_tokens(rows: jnp.ndarray, src: jnp.ndarray, pos: jnp.ndarray,
+               w: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """``sum_k w[t, k] * (the row of copy (t, k))``, (T, d) in float32 with
+    ``w`` 1 where None: the rows of a routed buffer, row ``c`` holding copy
+    ``src[c]`` (an index into the T*K copies, ``pos`` (T, K) their rows),
+    added into their tokens.  A scatter-add of the buffer's rows, which
+    timed faster at train-moonlight-ep8's capped shapes than gathering the
+    T*K copies from it (PERF.md §6); the buffer's rows of no held copy
+    are 0."""
+    T, K = pos.shape
+    rows = rows.astype(jnp.float32)
+    if w is not None:
+        rows = rows * jnp.take(w.reshape(-1), src)[:, None]
+    return jnp.zeros((T, rows.shape[1]), jnp.float32).at[src // K].add(rows)
+
+
+@jax.custom_vjp
+def _dispatch_held(x: jnp.ndarray, src: jnp.ndarray,
+                   pos: jnp.ndarray) -> jnp.ndarray:
+    """The routed buffer: row ``c`` is the token of copy ``src[c]``.  Its
+    gradient adds each row into its token (:func:`_to_tokens`)."""
+    return jnp.take(x, src // pos.shape[1], axis=0)
+
+
+def _dispatch_held_fwd(x, src, pos):
+    return _dispatch_held(x, src, pos), (src, pos)
+
+
+def _dispatch_held_bwd(res, g):
+    src, pos = res
+    return _to_tokens(g, src, pos, None).astype(g.dtype), None, None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(y_rows: jnp.ndarray, w: jnp.ndarray, src: jnp.ndarray,
+                  pos: jnp.ndarray) -> jnp.ndarray:
+    """Each token's copies from the routed buffer ``y_rows``, weighted by
+    ``w`` (T, K) and summed in float32 (:func:`_to_tokens`), in the rows'
+    dtype.  Its gradients gather ``len(y_rows)`` rows, where a
+    scatter-add's would gather all T*K copies."""
+    return _to_tokens(y_rows, src, pos, w).astype(y_rows.dtype)
+
+
+def _combine_held_fwd(y_rows, w, src, pos):
+    return _combine_held(y_rows, w, src, pos), (y_rows, w, src, pos)
+
+
+def _combine_held_bwd(res, g):
+    y_rows, w, src, pos = res
+    g_rows = jnp.take(g, src // w.shape[1], axis=0).astype(jnp.float32)
+    d_rows = g_rows * jnp.take(w.reshape(-1), src)[:, None]
+    d_w = jnp.sum(g_rows * y_rows.astype(jnp.float32), axis=-1)
+    d_w = jnp.take(d_w, pos.reshape(-1), mode="fill",
+                   fill_value=0).reshape(w.shape)
+    return d_rows.astype(y_rows.dtype), d_w, None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def _routed_held(cap, xt, w_gate, w_up, w_down, top_w, order, back, sizes):
+    """The held experts' part of the layer, (T, d), through a buffer of
+    ``cap`` rows, which must hold every copy routed here.  ``order`` (with
+    inverse ``back``) sorts the T*K token copies by expert, the copies
+    routed elsewhere last, so the buffer is the first ``cap`` of ``order``;
+    its rows after the held copies feed no group and come out of the
+    grouped matmuls 0.  Back in token order, each token's copies are summed
+    in float32 by their weights ``top_w`` (T, K)."""
+    T, K = top_w.shape
+    with scope("dispatch"):
+        src = order[:cap]
+        pos = jnp.where(back < sizes.sum(), back, cap).reshape(T, K)
+        rows = _dispatch_held(xt, src, pos)
+    with scope("experts"):
+        y_rows = _experts_swiglu(rows, w_gate, w_up, w_down, sizes)
+    with scope("dispatch"):
+        return _combine_held(y_rows, top_w, src, pos)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed_capped(cap, xt, w_gate, w_up, w_down, top_w, order, back,
+                   sizes):
+    """:func:`_routed_held` over ``cap`` rows where the copies held here
+    fit them, else over all T*K copies: no copy is ever dropped.  The
+    gradient keeps only the inputs and runs the taken branch again inside,
+    so that the untaken branch leaves no buffers (differentiating a
+    ``lax.cond`` keeps both branches' residuals)."""
+    return lax.cond(sizes.sum() <= cap, functools.partial(_routed_held, cap),
+                    functools.partial(_routed_held, order.size), xt, w_gate,
+                    w_up, w_down, top_w, order, back, sizes)
+
+
+def _routed_capped_fwd(cap, *args):
+    return _routed_capped(cap, *args), args
+
+
+def _routed_capped_bwd(cap, args, g):
+    *inputs, order, back, sizes = args
+
+    def pull(branch):
+        def run(*inputs):
+            _, vjp = jax.vjp(lambda *a: branch(*a, order, back, sizes),
+                             *inputs)
+            return vjp(g)
+        return run
+
+    grads = lax.cond(sizes.sum() <= cap,
+                     pull(functools.partial(_routed_held, cap)),
+                     pull(functools.partial(_routed_held, order.size)),
+                     *inputs)
+    return (*grads, None, None, None)
+
+
+_routed_capped.defvjp(_routed_capped_fwd, _routed_capped_bwd)
+
+
 @scope("mlp")
 def apply_routed_moe(p: Params, x: jnp.ndarray, *, n_experts: int,
                      top_k: int, routed_scale: float, first: int = 0
@@ -718,10 +839,14 @@ def apply_routed_moe(p: Params, x: jnp.ndarray, *, n_experts: int,
     ``top_k`` of ``s + select_bias`` are chosen, weighted by ``s`` over
     their sum times ``routed_scale``.  The token-expert assignments held
     here are sorted by expert and run as grouped matmuls; no token is
-    dropped.  Returns (y, aux): the sequence-wise balance loss over all
-    experts (DeepSeek-V3 eqs. 17-20, without its weight), each expert's
-    load (assignments of these tokens), the rows computed here and the
-    largest held expert's rows.
+    dropped.  Where the chip holds fewer than half the experts they are
+    gathered into a buffer of ``routed_cap`` rows, and into one of all
+    T*K copies only when more are routed here.  Returns (y, aux): the
+    sequence-wise balance loss over all experts (DeepSeek-V3 eqs. 17-20,
+    without its weight), each expert's load (assignments of these tokens),
+    the rows computed here, the largest held expert's rows, and
+    ``routed_overflow``: 1 where the buffer of all T*K copies ran (always
+    where no cap applies), else 0.
     """
     B, S, d = x.shape
     T, K = B * S, top_k
@@ -744,17 +869,16 @@ def apply_routed_moe(p: Params, x: jnp.ndarray, *, n_experts: int,
         sizes = jnp.bincount(group, length=n + 1)[:n]
         back = jnp.zeros((T * K,), order.dtype).at[order].set(
             jnp.arange(T * K, dtype=order.dtype))
-        rows = permute_rows(xt, order, back, K)
     with scope("experts"):
-        g = grouped_matmul(rows, cast(p["w_gate"], dt), sizes)
-        u = grouped_matmul(rows, cast(p["w_up"], dt), sizes)
-        y_rows = grouped_matmul(jax.nn.silu(g) * u, cast(p["w_down"], dt),
-                                sizes)
-    with scope("dispatch"):
-        # back to token order: the rows of experts held elsewhere are 0
-        y = permute_rows(y_rows, back, order, 1).reshape(T, K, d)
-        y = (y.astype(jnp.float32) * top_w[..., None]).sum(axis=1)
-        y = y.astype(dt).reshape(B, S, d)
+        experts = [cast(p[k], dt) for k in ("w_gate", "w_up", "w_down")]
+    cap = routed_cap(T, K, n, n_experts)
+    if cap is None:
+        y = _routed_held(T * K, xt, *experts, top_w, order, back, sizes)
+        overflow = jnp.ones((), jnp.float32)
+    else:
+        y = _routed_capped(cap, xt, *experts, top_w, order, back, sizes)
+        overflow = (sizes.sum() > cap).astype(jnp.float32)
+    y = y.reshape(B, S, d)
     if "shared" in p:
         with scope("shared"):
             y = y + apply_mlp(p["shared"], x, "swiglu")
@@ -768,7 +892,8 @@ def apply_routed_moe(p: Params, x: jnp.ndarray, *, n_experts: int,
         balance = jnp.mean(jnp.sum(f * pr.mean(1), axis=-1))
     aux = {"balance": balance, "expert_load": load,
            "routed_rows_here": sizes.sum().astype(jnp.float32),
-           "max_expert_load": sizes.max().astype(jnp.float32)}
+           "max_expert_load": sizes.max().astype(jnp.float32),
+           "routed_overflow": overflow}
     return y, aux
 
 

@@ -150,6 +150,105 @@ def test_held_shares_add_up_to_the_whole_layer():
     assert rows == float(aux["routed_rows_here"]) == 2 * 24 * moe_all.top_k
 
 
+@pytest.mark.parametrize("tokens, held, n_experts, cap", [
+    (8192, 8, 64, 12288),     # train-moonlight-ep8: a quarter of 49,152
+    (1024, 2, 16, 1536),
+    (1024, 1, 64, 512),       # at least one row tile
+    (8192, 32, 64, None),     # half the experts: the cap would be T*K
+    (8192, 64, 64, None),
+])
+def test_routed_cap(tokens, held, n_experts, cap):
+    """Twice the rows the held experts expect, in whole row tiles of the
+    grouped matmul, and no cap where that is not under T*K."""
+    assert cm.routed_cap(tokens, 6, held, n_experts) == cap
+
+
+def _routed_grads(p, x, moe, first=0, layer=None):
+    """The routed part's value and its gradients to x, the three expert
+    weights and the router, under a fixed projection of the output;
+    ``layer`` (p, x, moe, first) -> y in place of the routed layer."""
+    routed = {k: v for k, v in p.items() if k != "shared"}
+    proj = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def f(x, w):
+        if layer is not None:
+            y, aux = layer({**routed, **w}, x, moe, first), {}
+        else:
+            y, aux = _routed({**routed, **w}, x, moe, first)
+        return jnp.sum(y * proj), (y, aux)
+
+    keys = ("w_gate", "w_up", "w_down", "router")
+    (_, (y, aux)), grads = jax.value_and_grad(f, argnums=(0, 1),
+                                              has_aux=True)(
+        x, {k: p[k] for k in keys})
+    return y, aux, {"x": grads[0], **grads[1]}
+
+
+def _assert_same(got, want):
+    for k in want:
+        np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
+                                   rtol=1e-5, atol=1e-5, err_msg=k)
+
+
+@pytest.mark.parametrize("first", [0, 6])
+def test_capped_buffer_matches_the_full_one(monkeypatch, first):
+    """2 of 16 experts held, 1024 tokens of top-6 under an even router: the
+    held copies (about 768) fit the cap of 1536 rows, and the capped
+    buffer gives the full buffer's values and gradients, and the plain
+    form's."""
+    moe, p = _layer(E=16, held=2)
+    p = {**p, **{k: p[k][:2] for k in ("w_gate", "w_up", "w_down")}}
+    moe = dataclasses.replace(moe, experts_held=2)
+    x = _normal(2, 512, 32)
+    y, aux, grads = _routed_grads(p, x, moe, first)
+    assert float(aux["routed_overflow"]) == 0.0
+    assert 0 < float(aux["routed_rows_here"]) <= 1536
+    monkeypatch.setattr(cm, "routed_cap", lambda *a: None)
+    y_all, aux_all, grads_all = _routed_grads(p, x, moe, first)
+    assert float(aux_all["routed_overflow"]) == 1.0
+    _assert_same({"y": y, **grads}, {"y": y_all, **grads_all})
+    y_ref, _, grads_ref = _routed_grads(p, x, moe, first, _dense_moe)
+    _assert_same({"y": y, **grads}, {"y": y_ref, **grads_ref})
+
+
+def test_skewed_router_takes_the_exact_fallback(monkeypatch):
+    """Every token to experts 0..5 with experts 0 and 1 held here: 2048
+    copies overflow the cap of 1536 rows, the buffer of all copies runs
+    instead, and every copy is computed, as the plain form says; the
+    values and gradients are those of the uncapped layer and of the plain
+    form."""
+    moe, p = _layer(E=16, held=2)
+    p = {**p, **{k: p[k][:2] for k in ("w_gate", "w_up", "w_down")},
+         "select_bias": jnp.where(jnp.arange(16) < 6, 10.0, 0.0)}
+    moe = dataclasses.replace(moe, experts_held=2)
+    x = _normal(2, 512, 32)
+    y, aux, grads = _routed_grads(p, x, moe)
+    assert float(aux["routed_rows_here"]) == 2048
+    assert float(aux["routed_overflow"]) == 1.0
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(_dense_moe(p, x, moe)),
+                               rtol=1e-5, atol=1e-5)
+    monkeypatch.setattr(cm, "routed_cap", lambda *a: None)
+    y_all, _, grads_all = _routed_grads(p, x, moe)
+    _assert_same({"y": y, **grads}, {"y": y_all, **grads_all})
+    _, _, grads_ref = _routed_grads(p, x, moe, layer=_dense_moe)
+    _assert_same(grads, grads_ref)
+
+
+@pytest.mark.parametrize("held, conds", [(16, 0), (2, 2)])
+def test_a_chip_holding_every_expert_runs_no_cond(held, conds):
+    """The cap and its fallback exist only where a chip holds fewer than
+    half the experts: one ``cond`` forward and one backward, none where
+    the buffer of all copies is the only one."""
+    moe, p = _layer(E=16, held=16)
+    p = {**p, **{k: p[k][:held] for k in ("w_gate", "w_up", "w_down")}}
+    moe = dataclasses.replace(moe, experts_held=held)
+    x = _normal(2, 512, 32)
+    loss = lambda x: jnp.sum(_routed(p, x, moe)[0])
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss))(x))
+    assert jaxpr.count(" cond[") == conds
+
+
 def test_selection_bias_rule():
     """After a step each expert's bias moves by 1e-3 toward the mean load;
     the optimizer leaves it alone (no gradient, no decay)."""

@@ -42,25 +42,29 @@ _KERNEL = re.compile(
     r'op_name="[^"]*?(flash_fwd|flash_bwd_dq|flash_bwd_dkv)\W')
 
 
+def _traced_op(line, name):
+    """The kernel call of the compiled instruction ``line`` rebuilt as a
+    profiler trace names it (result and operand shapes with the call)."""
+    from bench.trace import Op
+    result = re.match(r"\s*%?[\w.\-]+ = (.*?) custom-call\(",
+                      line).group(1)
+    constraints = line.split("operand_layout_constraints={", 1)[1]
+    constraints = re.sub(r"\{[^{}]*\}", "", constraints).split("}")[0]
+    operands = re.findall(r"[a-z]+\d*\[[\d,]*\]", constraints)
+    return Op(0.0, 1.0, (
+        f"%{name}.1 = {result} custom-call({', '.join(operands)}), "
+        f'custom_call_target="tpu_custom_call"'))
+
+
 def _flash_calls(text):
     """``{kernel name: bench.trace.Op}`` for each flash kernel call in the
-    compiled text, the instruction rebuilt as a profiler trace names it
-    (result and operand shapes with the call)."""
-    from bench.trace import Op
+    compiled text."""
     calls = {}
     for line in text.splitlines():
         name = _KERNEL.search(line)
         if 'custom_call_target="tpu_custom_call"' not in line or not name:
             continue
-        result = re.match(r"\s*%?[\w.\-]+ = (.*?) custom-call\(",
-                          line).group(1)
-        constraints = line.split("operand_layout_constraints={", 1)[1]
-        constraints = re.sub(r"\{[^{}]*\}", "", constraints).split("}")[0]
-        operands = re.findall(r"[a-z]+\d*\[[\d,]*\]", constraints)
-        calls[name.group(1)] = Op(0.0, 1.0, (
-            f"%{name.group(1)}.1 = {result} custom-call("
-            f"{', '.join(operands)}), "
-            f'custom_call_target="tpu_custom_call"'))
+        calls[name.group(1)] = _traced_op(line, name.group(1))
     return calls
 
 
@@ -141,3 +145,47 @@ def test_mla_flash_train_compiles(one_chip, backward):
         assert mla_flash_bwd.call_cost(calls["flash_bwd_dkv"]) == (0.0, 0.0)
         assert mla_flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
         assert flash_fwd.call_cost(calls["flash_bwd_dq"]) is None
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_routed_expert_layer_compiles(one_chip, monkeypatch, backward):
+    """The expert layer at train-moonlight-ep8's shapes: 8192 tokens of
+    2048, top-6 of 64 experts, 8 of them held, of 1408.  Its grouped
+    matmuls (megablox ``gmm``, and ``tgmm`` for the weight gradient) run
+    over the capped buffer of 12,288 rows and, in the fallback, over all
+    49,152 copies; the benchmark's reader recognises every call."""
+    from bench.kernels import moe_experts
+    from repro.models import common as cm
+    monkeypatch.setattr(cm, "_attention_kernels",
+                        lambda: {"interpret": False})
+    E, n, d, f = 64, 8, 2048, 1408
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    p = {"router": sds((d, E), jnp.float32),
+         "select_bias": sds((E,), jnp.float32),
+         "w_gate": sds((n, d, f), jnp.float32),
+         "w_up": sds((n, d, f), jnp.float32),
+         "w_down": sds((n, f, d), jnp.float32)}
+
+    def layer(p, x):
+        return cm.apply_routed_moe(p, x, n_experts=E, top_k=6,
+                                   routed_scale=2.446)[0]
+
+    fn = layer
+    if backward:
+        fn = jax.grad(lambda p, x: layer(p, x).astype(jnp.float32).sum(),
+                      argnums=(0, 1))
+    text = _compiled_text(fn, p, sds((1, 8192, d), jnp.bfloat16))
+    rows = []
+    for line in text.splitlines():
+        kernel = re.match(r"\s*%(t?gmm)\.\d+ = ", line)
+        if not kernel or 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        op = _traced_op(line, kernel.group(1))
+        assert moe_experts.call_cost(op, n / E) is not None, line
+        rows.append(int(re.search(r"\[(\d+),", op.shape if kernel.group(
+            1) == "gmm" else op.operand_shapes[-1]).group(1)))
+    # forward: gate, up and down; backward: those again inside the
+    # gradient, the two gradients of each input and the three weights'
+    per_branch = 9 if backward else 3
+    assert sorted(rows) == [12288] * per_branch + [49152] * per_branch
