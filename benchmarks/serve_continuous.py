@@ -26,7 +26,7 @@ Five claims, measured:
    pass re-plans the decode phases on the *quantized* workload model
    (halved cache-read stream): the roofline feedback loop, recorded as
    per-bucket planned energy vs the bf16 plan at the same tau.
-5. **Planner cost** — wall time of the (vectorized) phase-bundle planning
+5. **Planner cost** — wall time of the (vectorized) per-phase planning
    itself, the number future PRs diff against.
 
 Besides the usual artifact, the run writes a repo-root ``BENCH_serve.json``
@@ -278,8 +278,8 @@ def planner_feedback_section(kv_dtype: str = KV_DTYPE,
     from repro.configs import REGISTRY
     from repro.configs.base import ShapeConfig
     from repro.core.objectives import WastePolicy
-    from repro.core.phase_plan import plan_phase_bundle
     from repro.core.power_model import get_chip
+    from repro.dvfs import plan_serve_phases
 
     full = REGISTRY[ARCH]
     chip = get_chip("tpu-v5e")
@@ -289,13 +289,13 @@ def planner_feedback_section(kv_dtype: str = KV_DTYPE,
                       global_batch=2 * SLOTS, kind="decode")
     metas: Dict[str, Dict] = {}
     for kvd in (None, kv_dtype):
-        bundle = plan_phase_bundle(
+        plan = plan_serve_phases(
             full, chip, n_slots=2 * SLOTS, prefill_shape=pre,
             decode_shape=dec, policy=WastePolicy(TAU), n_reps=n_reps,
             kv_dtype=kvd)
         metas[kvd or "bf16"] = {
-            ph: p.schedule.meta for ph, p in bundle.phases().items()
-            if ph.startswith("decode@")}
+            s.name: s.schedule.meta for s in plan.segments
+            if s.scope == "serve-decode"}
 
     buckets: Dict[str, Dict] = {}
     for ph in sorted(metas["bf16"], key=lambda s: int(s.split("@")[1])):
@@ -331,8 +331,8 @@ def main(verbose: bool = True, kv_dtype: str = KV_DTYPE) -> Dict:
 
     # --- 4. DVFS: plan the full-size arch, replay through the engine ----
     # One DvfsSession runs campaign -> plan -> govern -> meter; the
-    # kernel-static governor + simulated controller reproduce the legacy
-    # plan_phase_bundle/PhaseExecutor pipeline bit-for-bit.
+    # kernel-static governor + simulated controller replay the planned
+    # schedules as they are.
     full = REGISTRY[ARCH]
     pre = ShapeConfig(name="serve_prefill", seq_len=512, global_batch=1,
                       kind="prefill")
@@ -418,7 +418,7 @@ def main(verbose: bool = True, kv_dtype: str = KV_DTYPE) -> Dict:
               f"{qp['peak_allocated_pages']}/{qp['n_pages']} pages")
         print(f"  compile    : {out['compile_stats']}")
         print(f"  planner    : {planner_wall_s:.2f}s wall "
-              f"(vectorized phase-bundle planning)")
+              f"(vectorized per-phase planning)")
         print(f"  tracing    : {overhead['trace_overhead']:.3f}x tokens/s "
               f"with tracer attached ({overhead['traced_events']} events); "
               f"trace -> {trace_path}")

@@ -88,10 +88,10 @@ def main(verbose: bool = True) -> Dict:
     cfg, shape, chip = h["cfg"], h["shape"], h["chip"]
     kernel, passl = h["kernel"], h["pass"]
     policy = WastePolicy(TAU)
-    kernel_bundle = h["kernel_sess"].plan.to_train_bundle()
+    kernel_plan = h["kernel_sess"].plan
 
     transfer = [r.to_dict() for r in
-                compare_transfer(kernel_bundle, cfg, chip, shape,
+                compare_transfer(kernel_plan, cfg, chip, shape,
                                  list(MESHES), policy, n_reps=N_REPS)]
     max_vs_replan = max(abs(r["energy_vs_replan_pct"]) for r in transfer)
 
@@ -104,7 +104,7 @@ def main(verbose: bool = True) -> Dict:
         "max_transfer_vs_replan_pct": max_vs_replan,
         "kernel_beats_pass": kernel["totals"]["energy_pct"]
         < passl["totals"]["energy_pct"],
-        "bundle_summary": kernel_bundle.summary(),
+        "plan_summary": kernel_plan.summary(),
     }
     save_artifact("train_dvfs", out)
 

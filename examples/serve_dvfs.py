@@ -87,12 +87,10 @@ def main():
     drift = [1] * 2 + [2] * 13 + [4]       # observed mix ~ {.12,.81,.06}
 
     def serve_plan(mix):
-        from repro.dvfs import DvfsPlan, PlanSegment
-        from repro.core import compile_phase
+        from repro.dvfs import DvfsPlan, compile_phase
         segs = plan_decode_joint(tables, mix, chip, policy)
-        pre = PlanSegment.from_phase_plan(
-            compile_phase(tables[1], "prefill", chip, policy),
-            scope="serve-prefill")
+        pre = compile_phase(tables[1], "prefill", chip, policy,
+                            scope="serve-prefill")
         return DvfsPlan(chip_name=chip.name, kind="serve",
                         segments=[pre] + segs,
                         meta={"decode_mix": dict(mix)})

@@ -5,7 +5,9 @@ from .freq import AUTO, ClockPair, FrequencyGrid, paper_grid_3080ti, \
 from .power_model import Chip, KernelSpec, get_chip, rtx3080ti_like, \
     a4000_like, tpu_v5e_like, CHIPS
 from .workload import (WorkloadBuilder, build_workload, workload_totals,
-                       decode_slot_buckets, decode_bucket_workloads)
+                       decode_slot_buckets, decode_bucket_workloads,
+                       train_phase_of, TRAIN_PHASES,
+                       calibrate_workload_against_hlo)
 from .measure import Campaign, MeasurementTable, NoiseModel
 from .objectives import WastePolicy, edp, ed2p, compute_waste, pct
 from .planner import (Plan, local_plan, global_plan, global_plan_dp,
@@ -15,10 +17,6 @@ from .coalesce import CoalescedPlan, coalesced_global_plan, expand_sequence
 from .search import search_plan, SearchReport, evaluate_against_truth
 from .schedule import DVFSSchedule, ScheduleEntry, schedule_from_plan, \
     schedule_from_coalesced
-from .phase_plan import (PhasePlan, PhasePlanBundle, plan_phase_bundle,
-                         TrainPlanBundle, plan_train_bundle, compile_phase,
-                         train_phase_of, TRAIN_PHASES,
-                         calibrate_workload_against_hlo)
 
 __all__ = [
     "AUTO", "ClockPair", "FrequencyGrid", "paper_grid_3080ti",
@@ -32,8 +30,6 @@ __all__ = [
     "DVFSSchedule", "ScheduleEntry", "schedule_from_plan",
     "schedule_from_coalesced", "search_plan", "SearchReport",
     "evaluate_against_truth", "decode_slot_buckets",
-    "decode_bucket_workloads", "PhasePlan", "PhasePlanBundle",
-    "plan_phase_bundle", "TrainPlanBundle", "plan_train_bundle",
-    "compile_phase", "train_phase_of", "TRAIN_PHASES",
+    "decode_bucket_workloads", "train_phase_of", "TRAIN_PHASES",
     "calibrate_workload_against_hlo",
 ]

@@ -632,8 +632,7 @@ def decode_slot_buckets(n_slots: int) -> List[int]:
 
 def pick_decode_bucket(buckets: List[int], n_active: int) -> int:
     """THE bucket-routing rule: smallest bucket >= n_active (largest if
-    none).  Single-sourced so the plan IR and the legacy bundle shim can
-    never route decode steps differently."""
+    none)."""
     if not buckets:
         raise KeyError("no decode buckets to route to")
     for b in buckets:
@@ -663,3 +662,40 @@ def workload_totals(kernels: List[KernelSpec]):
     h = sum(k.hbm_bytes * k.invocations for k in kernels)
     i = sum(k.ici_bytes * k.invocations for k in kernels)
     return f, h, i
+
+
+TRAIN_PHASES = ("fwd", "bwd", "opt")
+
+# workload-builder kernel phase tag -> train phase.  The ``loss`` pass
+# (lm-head fwd + softmax + lm-head grads) runs contiguously at the fwd/bwd
+# boundary; folding it into ``fwd`` keeps the boundary switch count
+# unchanged while leaving ``bwd`` the pure backward pass.
+_KERNEL_PHASE_TO_TRAIN = {"embed": "fwd", "fwd": "fwd", "loss": "fwd",
+                          "bwd": "bwd", "opt": "opt"}
+
+
+def train_phase_of(kernel: KernelSpec) -> str:
+    """Map a workload-builder kernel to its train phase (fwd|bwd|opt)."""
+    return _KERNEL_PHASE_TO_TRAIN.get(kernel.phase, "fwd")
+
+
+def calibrate_workload_against_hlo(kernels: List[KernelSpec],
+                                   hlo_text: str) -> Dict:
+    """Cross-check the analytic workload against compiled-HLO accounting.
+
+    Parses the post-optimization HLO of the jitted train step with
+    :func:`~repro.hw.hlo_parse.analyze_hlo` (trip-count-corrected, so
+    scan-over-layers and grad-accumulation loops count fully) and reports
+    the analytic/HLO ratio for FLOPs and HBM bytes.  Stored in the
+    plan's meta so a shipped plan records how faithful its workload
+    decomposition was to the compiled program.
+    """
+    from ..hw.hlo_parse import analyze_hlo
+    ana = analyze_hlo(hlo_text)
+    flops, hbm, _ = workload_totals(kernels)
+    return {
+        "analytic_flops": flops, "hlo_flops": ana.flops,
+        "flops_ratio": flops / ana.flops if ana.flops else None,
+        "analytic_hbm_bytes": hbm, "hlo_hbm_bytes": ana.hbm_bytes,
+        "hbm_ratio": hbm / ana.hbm_bytes if ana.hbm_bytes else None,
+    }

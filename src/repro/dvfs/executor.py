@@ -19,8 +19,12 @@ re-planning without losing pre-drift records.
   ``fwd`` → ``bwd`` → ``opt`` back-to-back, and round-trips its books
   through ``state_dict()`` / ``load_state_dict()`` for checkpoint-restart.
 
-The legacy :class:`~repro.runtime.dvfs_exec.PhaseExecutor` /
-``TrainPhaseExecutor`` are thin deprecation shims over these two.
+To replay a fixed plan, wrap it in a static governor::
+
+    TrainGovernorExecutor(StaticPlanGovernor(plan), chip, controller)
+
+or let :class:`~repro.dvfs.session.DvfsSession` build the executor
+(``session.train_executor()`` / ``session.serve_executor()``).
 """
 from __future__ import annotations
 
@@ -34,8 +38,8 @@ from ..core.power_model import Chip
 from ..obs import NULL_TRACER, MetricsRegistry, segment_breakdown
 from ..runtime.energy import (EnergyMeter, FrequencyController,
                               SimulatedController, StepEnergy)
-from .governors import BaseGovernor, StaticPlanGovernor
-from .plan_ir import DvfsPlan, PlanSegment
+from .governors import BaseGovernor
+from .plan_ir import PlanSegment
 
 TRAIN_SCOPE_ORDER = ("train-fwd", "train-bwd", "train-opt")
 
@@ -53,7 +57,7 @@ class GovernorExecutor:
             raise ValueError("governor has no plan to execute; plan first "
                              "(DvfsSession.plan_* or governor.adopt)")
         if plan.chip_name != chip.name:
-            raise ValueError(f"bundle planned for {plan.chip_name!r}, "
+            raise ValueError(f"schedules planned for {plan.chip_name!r}, "
                              f"executing on {chip.name!r}")
         self.governor = governor
         self.chip = chip
@@ -333,12 +337,6 @@ class GovernorExecutor:
 class ServeGovernorExecutor(GovernorExecutor):
     """Serving adapter: the engine calls the phase-transition hooks."""
 
-    @classmethod
-    def from_bundle(cls, bundle, chip: Chip, controller=None, **kw
-                    ) -> "ServeGovernorExecutor":
-        gov = StaticPlanGovernor(DvfsPlan.from_phase_bundle(bundle))
-        return cls(gov, chip, controller, **kw)
-
     # -- phase hooks ------------------------------------------------------
     def on_prefill(self, frac: float = 1.0) -> StepEnergy:
         # by scope, not by name — prefill segments may be named freely.
@@ -359,12 +357,6 @@ class TrainGovernorExecutor(GovernorExecutor):
                  controller=None, **kw):
         super().__init__(governor, chip, controller, **kw)
         self.last_step: Optional[int] = None
-
-    @classmethod
-    def from_bundle(cls, bundle, chip: Chip, controller=None, **kw
-                    ) -> "TrainGovernorExecutor":
-        gov = StaticPlanGovernor(DvfsPlan.from_train_bundle(bundle))
-        return cls(gov, chip, controller, **kw)
 
     def _phase_names(self):
         plan = self.governor.plan

@@ -35,11 +35,11 @@ import numpy as np
 
 from ..core.measure import MeasurementTable
 from ..core.objectives import WastePolicy
-from ..core.phase_plan import compile_phase
 from ..core.planner import (Plan, edp_global_plan, edp_local_plan,
                             edp_pass_plan, global_plan, local_plan,
                             pass_level_plan)
 from ..core.power_model import Chip
+from .phase_plan import compile_phase
 from .plan_ir import DvfsPlan, PlanSegment
 
 
@@ -90,7 +90,7 @@ class BaseGovernor:
     """Shared plan ownership + the default (no-feedback) control loop."""
 
     name = "?"
-    #: planner handed to plan_phase_bundle/plan_train_bundle; None means
+    #: planner handed to plan_serve_phases/plan_train_phases; None means
     #: the switch-aware coalesced default.
     phase_planner: Optional[Callable[..., Plan]] = None
 
@@ -136,9 +136,8 @@ class BaseGovernor:
                         bucket: Optional[int] = None) -> PlanSegment:
         """Compile one phase table into a deployable, switch-aware
         segment using this governor's planning strategy."""
-        pp = compile_phase(table, name, chip, self.policy,
-                           self.phase_planner)
-        return PlanSegment.from_phase_plan(pp, scope=scope, bucket=bucket)
+        return compile_phase(table, name, chip, self.policy,
+                             self.phase_planner, scope=scope, bucket=bucket)
 
     def plan_table(self, table: MeasurementTable, *,
                    meta: Optional[Dict] = None) -> DvfsPlan:
@@ -269,10 +268,9 @@ def plan_decode_joint(tables: Dict[int, MeasurementTable],
             tau_b = max(t_b / t_auto - 1.0, 0.0)
         else:
             tau_b = policy.tau          # unseen bucket: local budget
-        pp = compile_phase(t, f"decode@{b}", chip, WastePolicy(tau_b))
-        seg = PlanSegment.from_phase_plan(pp, scope="serve-decode",
-                                          granularity="kernel", bucket=b)
-        segments.append(seg)
+        segments.append(compile_phase(t, f"decode@{b}", chip,
+                                      WastePolicy(tau_b),
+                                      scope="serve-decode", bucket=b))
     return segments
 
 

@@ -1,15 +1,11 @@
-"""The unified DVFS plan IR: one serializable artifact for every
+"""The DVFS plan IR: the one serializable plan type, for every
 granularity the paper compares.
 
 The paper chooses frequency *policies* at different granularities (kernel
-vs pass vs iteration, §5–6) and the repo historically grew one ad-hoc
-type per granularity: :class:`~repro.core.planner.Plan` (one iteration,
-per-kernel choices), :class:`~repro.core.phase_plan.PhasePlanBundle`
-(serving: prefill + decode-by-bucket) and
-:class:`~repro.core.phase_plan.TrainPlanBundle` (training: fwd/bwd/opt).
-``DvfsPlan`` subsumes all of them: a flat list of *segments*, each a
-deployable :class:`~repro.core.schedule.DVFSSchedule` plus the kernels it
-covers, tagged with
+vs pass vs iteration, §5–6).  ``DvfsPlan`` carries all of them: a flat
+list of *segments*, each a deployable
+:class:`~repro.core.schedule.DVFSSchedule` plus the kernels it covers,
+tagged with
 
 * ``granularity`` — how clocks vary inside the segment
   (``kernel`` | ``phase`` | ``pass`` | ``iteration``), and
@@ -17,12 +13,10 @@ covers, tagged with
   ``serve-decode`` with a slot-count ``bucket``, ``train-fwd`` /
   ``train-bwd`` / ``train-opt``, or ``iteration`` for whole-step plans).
 
-The JSON wire format is versioned (``schema_version``); loaders reject
-plans written by a *newer* schema instead of misreading them.  Converters
-to/from the legacy types are lossless — the legacy bundles now implement
-their own ``to_json`` / ``from_json`` / ``save`` / ``load`` / ``summary``
-by round-tripping through this IR, so there is exactly one serialization
-and one reporting implementation.
+The phase planners (:mod:`repro.dvfs.phase_plan`) emit it, the governors
+own it, and the executors replay it.  The JSON wire format is versioned
+(``schema_version``); loaders reject plans written by a *newer* schema
+instead of misreading them, and reject documents that are not plans.
 """
 from __future__ import annotations
 
@@ -30,8 +24,9 @@ import dataclasses
 import json
 import os
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
+from ..core.freq import AUTO
 from ..core.power_model import KernelSpec
 from ..core.schedule import DVFSSchedule, schedule_from_plan
 
@@ -99,19 +94,23 @@ class PlanSegment:
                        json.dumps(d["schedule"])),
                    kernels=[KernelSpec(**k) for k in d["kernels"]])
 
-    # -- legacy bridge ---------------------------------------------------
-    def to_phase_plan(self):
-        from ..core.phase_plan import PhasePlan
-        return PhasePlan(name=self.name, schedule=self.schedule,
-                         kernels=self.kernels)
+    def kernel_clock_pairs(self) -> List[Tuple[object, object]]:
+        """Per-kernel dominant (mem, core) pair, indexed like ``kernels``.
 
-    @classmethod
-    def from_phase_plan(cls, plan, *, scope: str, granularity: str = None,
-                        bucket: Optional[int] = None) -> "PlanSegment":
-        gran = granularity or _granularity_from_meta(plan.schedule.meta)
-        return cls(name=plan.name, schedule=plan.schedule,
-                   kernels=plan.kernels, granularity=gran, scope=scope,
-                   bucket=bucket)
+        A coalesced schedule may assign different clocks to different
+        *instances* of the same kernel; the dominant pair (most instances)
+        is what DP/TP plan transfer replays on the resharded workload.
+        Kernels absent from the schedule fall back to AUTO.
+        """
+        counts: List[Dict[Tuple[object, object], int]] = \
+            [{} for _ in self.kernels]
+        for e in self.schedule.entries:
+            for ki, cnt in (e.kernel_idx or []):
+                d = counts[int(ki)]
+                key = (e.mem, e.core)
+                d[key] = d.get(key, 0) + int(cnt)
+        return [max(d.items(), key=lambda kv: kv[1])[0] if d
+                else (AUTO, AUTO) for d in counts]
 
 
 @dataclass
@@ -190,6 +189,8 @@ class DvfsPlan:
 
     @classmethod
     def from_dict(cls, d: Dict) -> "DvfsPlan":
+        if not isinstance(d, dict) or "segments" not in d:
+            raise ValueError("not a DvfsPlan document: no 'segments' key")
         version = int(d.get("schema_version", 1))
         if version > SCHEMA_VERSION:
             raise ValueError(
@@ -218,8 +219,7 @@ class DvfsPlan:
             return cls.from_json(f.read())
 
     def summary(self) -> Dict:
-        """Per-segment expected time/energy vs auto + switch counts; the
-        single reporting implementation both legacy bundles delegate to."""
+        """Per-segment expected time/energy vs auto + switch counts."""
         rows = {}
         for s in self.segments:
             m = s.schedule.meta
@@ -231,11 +231,10 @@ class DvfsPlan:
             }
         return {"chip": self.chip_name, "phases": rows, "meta": self.meta}
 
-    # -- lossless converters from/to the legacy plan types ---------------
     @classmethod
     def from_kernel_plan(cls, plan, *, meta: Optional[Dict] = None,
                          granularity: Optional[str] = None) -> "DvfsPlan":
-        """Wrap a legacy per-iteration :class:`~repro.core.planner.Plan`."""
+        """Wrap a per-iteration :class:`~repro.core.planner.Plan`."""
         sched = schedule_from_plan(plan)
         seg = PlanSegment(name="iteration", schedule=sched,
                           kernels=plan.table.kernels,
@@ -245,50 +244,12 @@ class DvfsPlan:
         return cls(chip_name=plan.table.chip_name, kind="iteration",
                    segments=[seg], meta=dict(meta or {}))
 
-    @classmethod
-    def from_phase_bundle(cls, bundle) -> "DvfsPlan":
-        segs = [PlanSegment.from_phase_plan(bundle.prefill,
-                                            scope="serve-prefill")]
-        for b in bundle.buckets:
-            segs.append(PlanSegment.from_phase_plan(
-                bundle.decode[b], scope="serve-decode", bucket=b))
-        return cls(chip_name=bundle.chip_name, kind="serve", segments=segs,
-                   meta=dict(bundle.meta))
-
     def prefill_segment(self) -> PlanSegment:
         """The serve-prefill segment, found by scope (names are free)."""
         for s in self.segments:
             if s.scope == "serve-prefill":
                 return s
         raise KeyError("plan has no serve-prefill segment")
-
-    def to_phase_bundle(self):
-        from ..core.phase_plan import PhasePlanBundle
-        if self.kind != "serve":
-            raise ValueError(f"kind={self.kind!r} plan is not a serve "
-                             f"bundle")
-        prefill = self.prefill_segment().to_phase_plan()
-        decode = {s.bucket: s.to_phase_plan() for s in self.segments
-                  if s.scope == "serve-decode"}
-        return PhasePlanBundle(chip_name=self.chip_name, prefill=prefill,
-                               decode=decode, meta=dict(self.meta))
-
-    @classmethod
-    def from_train_bundle(cls, bundle) -> "DvfsPlan":
-        segs = [PlanSegment.from_phase_plan(bundle.phases[ph],
-                                            scope=f"train-{ph}")
-                for ph in bundle.phase_names()]
-        return cls(chip_name=bundle.chip_name, kind="train", segments=segs,
-                   meta=dict(bundle.meta))
-
-    def to_train_bundle(self):
-        from ..core.phase_plan import TrainPlanBundle
-        if self.kind != "train":
-            raise ValueError(f"kind={self.kind!r} plan is not a train "
-                             f"bundle")
-        phases = {s.name: s.to_phase_plan() for s in self.segments}
-        return TrainPlanBundle(chip_name=self.chip_name, phases=phases,
-                               meta=dict(self.meta))
 
 
 def derive_role_plan(plan: DvfsPlan, role: str) -> DvfsPlan:

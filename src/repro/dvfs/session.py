@@ -2,7 +2,7 @@
 
 One context manager replaces the hand-wired pipeline every benchmark and
 example used to rebuild (build workload, run campaign, call the right
-planner, construct the right bundle, wire the right executor)::
+planner, wire the right executor)::
 
     from repro.dvfs import DvfsSession
 
@@ -20,11 +20,11 @@ planner, construct the right bundle, wire the right executor)::
         trainer = Trainer(..., executor=sess.train_executor())
 
 The session owns one governor (by name or instance), one controller
-backend, and at most one plan at a time.  Planning delegates to the
-legacy ``plan_phase_bundle`` / ``plan_train_bundle`` pipelines and
-converts the result through the lossless IR bridge, so a session-planned
-``DvfsPlan`` reproduces the legacy artifacts bit-for-bit — same campaign
-seed, same planner, same schedules.  On exit the session returns the
+backend, and at most one plan at a time.  Planning calls
+:func:`~repro.dvfs.phase_plan.plan_serve_phases` /
+:func:`~repro.dvfs.phase_plan.plan_train_phases` with the governor's
+phase planner and the session's campaign seed, then stamps the
+governor's name into the plan meta.  On exit the session returns the
 chip to the auto governor and freezes the report.
 """
 from __future__ import annotations
@@ -35,12 +35,12 @@ from typing import Dict, Optional, Union
 from ..configs.base import ModelConfig, ShapeConfig
 from ..core.measure import Campaign, MeasurementTable
 from ..core.objectives import WastePolicy
-from ..core.phase_plan import plan_phase_bundle, plan_train_bundle
 from ..core.power_model import Chip, get_chip
 from ..core.workload import WorkloadBuilder
 from .executor import (GovernorExecutor, ServeGovernorExecutor,
                        TrainGovernorExecutor)
 from .governors import BaseGovernor, governor as make_governor
+from .phase_plan import plan_serve_phases, plan_train_phases
 from .plan_ir import DvfsPlan, derive_role_plan
 
 
@@ -128,16 +128,15 @@ class DvfsSession:
         compute-tilted prefill segment, decode replicas stamp their
         memory-tilted role."""
         t0 = time.perf_counter()
-        bundle = plan_phase_bundle(
+        plan = plan_serve_phases(
             cfg, self.chip, n_slots=n_slots, prefill_shape=prefill_shape,
             decode_shape=decode_shape, policy=self.policy,
             planner=self.governor.phase_planner, seed=self.seed,
             n_reps=self.n_reps, tp=tp, dp=dp, kv_dtype=kv_dtype,
             meta=meta)
         self.planner_wall_s += time.perf_counter() - t0
-        plan = DvfsPlan.from_phase_bundle(bundle)
         plan.meta.setdefault("n_slots", int(n_slots))
-        # the bundle plans each decode bucket under its own (1+tau)*T_b
+        # the planner plans each decode bucket under its own (1+tau)*T_b
         # budget — implicitly a uniform-traffic assumption.  Record that
         # assumption so online governors measure mix drift against what
         # the *planner* believed (a skewed serve mix — e.g. prefix-cache
@@ -176,14 +175,13 @@ class DvfsSession:
         """Campaign + plan the fwd/bwd/opt phases of one train step with
         this session's governor; adopts and returns the plan."""
         t0 = time.perf_counter()
-        bundle = plan_train_bundle(
+        plan = plan_train_phases(
             cfg, self.chip, shape=shape, policy=self.policy,
             planner=self.governor.phase_planner, seed=self.seed,
             n_reps=self.n_reps, tp=tp, dp=dp,
             include_optimizer=include_optimizer, hlo_text=hlo_text,
             table=table, meta=meta)
         self.planner_wall_s += time.perf_counter() - t0
-        plan = DvfsPlan.from_train_bundle(bundle)
         plan.meta["governor"] = self.governor.name
         self.governor.adopt(plan, reason="plan_train")
         return plan

@@ -41,9 +41,8 @@ import numpy as np
 from ..core.freq import AUTO
 from ..core.measure import MeasurementTable
 from ..core.objectives import WastePolicy
-from ..core.phase_plan import compile_phase
 from ..dvfs.governors import OnlineGovernor
-from ..dvfs.plan_ir import PlanSegment
+from ..dvfs.phase_plan import compile_phase
 
 #: every fault kind a schedule may carry
 FAULT_KINDS = ("crash", "thermal-cap", "link-drop", "link-degrade",
@@ -338,10 +337,9 @@ def _replan_clamped(replica, reasons: List[str]) -> None:
                            "reason": list(reasons)})
     if replica.prefill_table is not None:
         seg = replica.plan.prefill_segment()
-        pp = compile_phase(replica.prefill_table, seg.name, replica.chip,
-                           WastePolicy(gov.policy.tau))
-        replica.plan.replace_segment(PlanSegment.from_phase_plan(
-            pp, scope="serve-prefill"))
+        replica.plan.replace_segment(compile_phase(
+            replica.prefill_table, seg.name, replica.chip,
+            WastePolicy(gov.policy.tau), scope="serve-prefill"))
 
 
 def apply_thermal_cap(replica, max_core_frac: float) -> None:

@@ -38,9 +38,8 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
 from ..core.objectives import WastePolicy
-from ..core.phase_plan import compile_phase
 from ..dvfs.governors import OnlineGovernor, plan_decode_joint
-from ..dvfs.plan_ir import PlanSegment
+from ..dvfs.phase_plan import compile_phase
 from ..obs import NULL_TRACER
 from .metering import LOADED_UTIL_MIN
 from .replica import DEAD, PARKED, Replica
@@ -136,9 +135,8 @@ class FleetGovernor:
         seg = r.plan.prefill_segment()
         if r.prefill_table is None:
             return seg.time_s, seg.energy_j
-        pp = compile_phase(r.prefill_table, seg.name, r.chip,
-                           WastePolicy(tau))
-        m = pp.schedule.meta
+        m = compile_phase(r.prefill_table, seg.name, r.chip, WastePolicy(tau),
+                          scope="serve-prefill").schedule.meta
         return float(m["time_s"]), float(m["energy_j"])
 
     @staticmethod
@@ -288,10 +286,9 @@ class FleetGovernor:
                 f"tau={pt.tau:.4f}:lambda={lam:.2e}"], refresh=False)
         if r.prefill_table is not None:
             seg = r.plan.prefill_segment()
-            pp = compile_phase(r.prefill_table, seg.name, r.chip,
-                               WastePolicy(pt.tau))
-            r.plan.replace_segment(PlanSegment.from_phase_plan(
-                pp, scope="serve-prefill"))
+            r.plan.replace_segment(compile_phase(
+                r.prefill_table, seg.name, r.chip, WastePolicy(pt.tau),
+                scope="serve-prefill"))
         self._applied[r.name] = pt.tau
         self.n_replans += 1
 

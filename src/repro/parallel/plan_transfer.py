@@ -4,9 +4,9 @@ The paper's §7–8 claim — "frequencies translate": a clock plan discovered
 once on a single device keeps (almost all of) its savings when the same
 model runs data-parallel (smaller per-device batch) or tensor-parallel
 (sharded kernels).  This module makes that claim executable for the
-training path: given a single-device
-:class:`~repro.core.phase_plan.TrainPlanBundle` and a
-:class:`~repro.launch.mesh.MeshSpec`, it derives the per-device bundle —
+training path: given a single-device train
+:class:`~repro.dvfs.plan_ir.DvfsPlan` and a
+:class:`~repro.launch.mesh.MeshSpec`, it derives the per-device plan —
 rebuilding the per-shard workload (per-device batch ``global_batch / dp``,
 kernels sharded ``tp`` ways, invocation counts and collective phases
 rescaled by the :class:`~repro.core.workload.WorkloadBuilder`), then
@@ -50,11 +50,11 @@ from ..core.coalesce import SWITCH_POWER_W, CoalescedPlan, expand_sequence
 from ..core.freq import ClockPair
 from ..core.measure import Campaign, MeasurementTable
 from ..core.objectives import WastePolicy, pct
-from ..core.phase_plan import (PhasePlan, TrainPlanBundle, compile_phase,
-                               plan_train_bundle, train_phase_of)
 from ..core.power_model import Chip, KernelSpec
 from ..core.schedule import schedule_from_coalesced
-from ..core.workload import WorkloadBuilder
+from ..core.workload import WorkloadBuilder, train_phase_of
+from ..dvfs.phase_plan import plan_train_phases
+from ..dvfs.plan_ir import DvfsPlan, PlanSegment
 from ..launch.mesh import MeshSpec
 
 # keep the name-matched clocks while |log AI_target - log AI_source| stays
@@ -84,28 +84,30 @@ def _match_pair(k: KernelSpec, src_kernels: Sequence[KernelSpec],
     return best if best is not None else named
 
 
-def transfer_train_bundle(src: TrainPlanBundle, cfg: ModelConfig,
-                          chip: Chip, shape: ShapeConfig, spec: MeshSpec,
-                          *, seed: int = 0, n_reps: int = 5,
-                          include_optimizer: Optional[bool] = None,
-                          include_comm: bool = False,
-                          name_pref: float = NAME_PREF_LOG_AI,
-                          repair_margin: float = REPAIR_MARGIN,
-                          table: Optional[MeasurementTable] = None
-                          ) -> TrainPlanBundle:
-    """Derive the per-device bundle for ``spec`` from a source bundle.
+def transfer_train_plan(src: DvfsPlan, cfg: ModelConfig,
+                        chip: Chip, shape: ShapeConfig, spec: MeshSpec,
+                        *, seed: int = 0, n_reps: int = 5,
+                        include_optimizer: Optional[bool] = None,
+                        include_comm: bool = False,
+                        name_pref: float = NAME_PREF_LOG_AI,
+                        repair_margin: float = REPAIR_MARGIN,
+                        table: Optional[MeasurementTable] = None
+                        ) -> DvfsPlan:
+    """Derive the per-device train plan for ``spec`` from a source plan.
 
-    The returned bundle's schedules carry exact per-shard accounting
+    The returned plan's schedules carry exact per-shard accounting
     (time/energy/switches of the *transferred* choices on the resharded
     measurement table), so it can be executed through
-    :class:`~repro.runtime.dvfs_exec.TrainPhaseExecutor` and compared
-    against a freshly-planned per-mesh bundle.  Per-phase meta records
+    :class:`~repro.dvfs.executor.TrainGovernorExecutor` and compared
+    against a freshly-planned per-mesh plan.  Per-segment meta records
     how many kernels were name-matched, roofline-remapped, and
     budget-repaired.  Pass a precomputed per-shard ``table`` to share one
     measurement campaign with a per-mesh replanning run.
     """
+    if src.kind != "train":
+        raise ValueError(f"kind={src.kind!r} plan is not a train plan")
     if src.chip_name != chip.name:
-        raise ValueError(f"bundle planned for {src.chip_name!r}, "
+        raise ValueError(f"source planned for {src.chip_name!r}, "
                          f"transferring onto {chip.name!r} — the source "
                          f"clock pairs would not exist in the target grid")
     tau = float(src.meta.get("tau", 0.0))
@@ -119,23 +121,22 @@ def transfer_train_bundle(src: TrainPlanBundle, cfg: ModelConfig,
         table = Campaign(chip, seed=seed, n_reps=n_reps).run(kernels)
     else:
         kernels = table.kernels
-    phases: Dict[str, PhasePlan] = {}
-    for ph in src.phase_names():
+    segments: List[PlanSegment] = []
+    for seg in src.segments:
+        ph = seg.scope[len("train-"):]
         mask = [train_phase_of(k) == ph for k in kernels]
         if not any(mask):
             continue
         sub = table.subset(mask)
-        src_phase = src.phases[ph]
-        src_pairs = src_phase.kernel_clock_pairs()
-        name_pair = {k.name: p for k, p in zip(src_phase.kernels,
-                                               src_pairs)}
+        src_pairs = seg.kernel_clock_pairs()
+        name_pair = {k.name: p for k, p in zip(seg.kernels, src_pairs)}
         pair_idx = {(p.mem, p.core): i for i, p in enumerate(sub.pairs)}
         vocab = sorted({pair_idx[p] for p in src_pairs if p in pair_idx}
                        | {sub.auto_idx})
         n_remapped = n_repaired = n_unmatched = 0
         kchoice: List[int] = []
         for i, k in enumerate(sub.kernels):
-            pair = _match_pair(k, src_phase.kernels, src_pairs, name_pref)
+            pair = _match_pair(k, seg.kernels, src_pairs, name_pref)
             if pair is None:
                 n_unmatched += 1
             elif pair != name_pair.get(k.name):
@@ -157,16 +158,19 @@ def transfer_train_bundle(src: TrainPlanBundle, cfg: ModelConfig,
                            switch_energy_j=chip.switch_latency_s
                            * SWITCH_POWER_W)
         sched = schedule_from_coalesced(
-            cp, meta={"phase": ph, "transferred_from": src.meta,
+            cp, meta={"phase": seg.name, "transferred_from": src.meta,
                       "n_kernels": len(sub.kernels),
                       "n_remapped": n_remapped,
                       "n_repaired": n_repaired,
                       "n_unmatched": n_unmatched})
-        phases[ph] = PhasePlan(name=ph, schedule=sched, kernels=sub.kernels)
+        segments.append(PlanSegment(
+            name=seg.name, schedule=sched, kernels=sub.kernels,
+            granularity="kernel", scope=seg.scope))
     md = dict(src.meta)
     md.update({"mesh": spec.describe(), "dp": dp, "tp": tp,
                "transferred": True})
-    return TrainPlanBundle(chip_name=chip.name, phases=phases, meta=md)
+    return DvfsPlan(chip_name=chip.name, kind="train", segments=segments,
+                    meta=md)
 
 
 @dataclass
@@ -195,13 +199,13 @@ class TransferRow:
         return d
 
 
-def compare_transfer(src: TrainPlanBundle, cfg: ModelConfig, chip: Chip,
+def compare_transfer(src: DvfsPlan, cfg: ModelConfig, chip: Chip,
                      shape: ShapeConfig, specs: Sequence[MeshSpec],
                      policy: WastePolicy, *, seed: int = 0,
                      n_reps: int = 5) -> List[TransferRow]:
     """Replay ``src`` on each mesh and compare to per-mesh replanning.
 
-    Both bundles are evaluated on literally the same per-mesh measurement
+    Both plans are evaluated on literally the same per-mesh measurement
     table, so the comparison isolates the plan, not the noise draw.
     """
     include_optimizer = bool(src.meta.get("include_optimizer", True))
@@ -213,17 +217,17 @@ def compare_transfer(src: TrainPlanBundle, cfg: ModelConfig, chip: Chip,
             cfg, shape, tp=spec.tp, dp=spec.data_extent,
             include_optimizer=include_optimizer).build()
         table = Campaign(chip, seed=mesh_seed, n_reps=n_reps).run(kernels)
-        xfer = transfer_train_bundle(src, cfg, chip, shape, spec,
-                                     table=table)
-        fresh = plan_train_bundle(
+        xfer = transfer_train_plan(src, cfg, chip, shape, spec,
+                                   table=table)
+        fresh = plan_train_phases(
             cfg, chip, shape=shape, policy=policy, table=table,
             tp=spec.tp, dp=spec.data_extent,
             include_optimizer=include_optimizer)
         xt = xe = ft = fe = bt = be = 0.0
         n_remapped = n_repaired = 0
-        for ph in xfer.phase_names():
-            xm = xfer.phases[ph].schedule.meta
-            fm = fresh.phases[ph].schedule.meta
+        for seg in xfer.segments:
+            xm = seg.schedule.meta
+            fm = fresh.segment(seg.name).schedule.meta
             xt += xm["time_s"]
             xe += xm["energy_j"]
             ft += fm["time_s"]
@@ -273,19 +277,19 @@ def _snap_clock(value, src_chip: Chip, dst_chip: Chip,
     return float(arr[int(np.argmin(np.abs(arr - target)))])
 
 
-def transfer_serve_plan(src, cfg: ModelConfig, chip: Chip, *,
+def transfer_serve_plan(src: DvfsPlan, cfg: ModelConfig, chip: Chip, *,
                         prefill_shape: ShapeConfig,
                         decode_shape: ShapeConfig,
                         tp: int = 1, dp: int = 1, seed: int = 0,
                         n_reps: int = 5,
                         repair_margin: float = REPAIR_MARGIN,
-                        tables: Optional[Dict] = None):
+                        tables: Optional[Dict] = None) -> DvfsPlan:
     """Derive a serve :class:`~repro.dvfs.DvfsPlan` for a *different
     chip model* from a plan discovered on another — §7–8's "frequencies
     translate" claim promoted from meshes to heterogeneous fleets.
 
     Per segment (prefill + each decode bucket), a three-stage
-    measurement-free mapping mirroring :func:`transfer_train_bundle`:
+    measurement-free mapping mirroring :func:`transfer_train_plan`:
 
     1. **Relative-frequency snap** — each kernel's source clock pair is
        read as a *fraction of fmax* per domain and snapped onto the
@@ -303,8 +307,6 @@ def transfer_serve_plan(src, cfg: ModelConfig, chip: Chip, *,
     chip) lets the caller share one campaign with the replica's online
     re-planning cache; missing phases are measured here.
     """
-    from ..dvfs.plan_ir import DvfsPlan, PlanSegment
-
     if src.kind != "serve":
         raise ValueError(f"kind={src.kind!r} plan is not a serve plan")
     if src.chip_name == chip.name:
@@ -334,7 +336,7 @@ def transfer_serve_plan(src, cfg: ModelConfig, chip: Chip, *,
     segments = []
     for seg in src.segments:
         table = target_table(seg)
-        src_pairs = seg.to_phase_plan().kernel_clock_pairs()
+        src_pairs = seg.kernel_clock_pairs()
         by_name = {k.name: p for k, p in zip(seg.kernels, src_pairs)}
         pair_idx = {(p.mem, p.core): i for i, p in enumerate(table.pairs)}
         mapped: List[int] = []

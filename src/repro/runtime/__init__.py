@@ -1,12 +1,10 @@
 from .energy import FrequencyController, SimulatedController, EnergyMeter, \
     StepEnergy
-from .dvfs_exec import PhaseExecutor, TrainPhaseExecutor
 from .ft import FailureInjector, InjectedFailure, StragglerWatchdog, \
     HeartbeatRegistry, StragglerEvent
 
 __all__ = [
     "FrequencyController", "SimulatedController", "EnergyMeter",
-    "StepEnergy", "PhaseExecutor", "TrainPhaseExecutor", "FailureInjector",
-    "InjectedFailure",
+    "StepEnergy", "FailureInjector", "InjectedFailure",
     "StragglerWatchdog", "HeartbeatRegistry", "StragglerEvent",
 ]

@@ -35,10 +35,10 @@ device buffers update in place; jitted callables are memoized per
 (chunk-len | prompt-bucket) and surfaced via :attr:`compile_stats`.
 
 When given a :class:`~repro.dvfs.ServeGovernorExecutor` (usually from
-:meth:`~repro.dvfs.DvfsSession.serve_executor`; the legacy
-``PhaseExecutor`` shim also qualifies), the engine replays the governor's
-:class:`~repro.dvfs.DvfsPlan` around every phase transition (prefill vs
-decode, bucketed by active-slot count) — the plan → runtime loop, closed.
+:meth:`~repro.dvfs.DvfsSession.serve_executor`), the engine replays the
+governor's :class:`~repro.dvfs.DvfsPlan` around every phase transition
+(prefill vs decode, bucketed by active-slot count) — the plan → runtime
+loop, closed.
 An :class:`~repro.dvfs.OnlineGovernor` additionally re-plans the decode
 segments when the observed bucket mix drifts from the planned one.
 """

@@ -13,8 +13,7 @@ DVFS integration comes in two tiers:
   time/energy of a fixed schedule each step (no actuation);
 * ``executor`` — active execution: a
   :class:`~repro.dvfs.TrainGovernorExecutor` (usually built with
-  :meth:`~repro.dvfs.DvfsSession.train_executor`; the legacy
-  ``TrainPhaseExecutor`` shim also qualifies) *actuates* the planned
+  :meth:`~repro.dvfs.DvfsSession.train_executor`) *actuates* the planned
   clocks around every step, replaying the governor's
   :class:`~repro.dvfs.DvfsPlan` ``fwd``/``bwd``/``opt`` segments through
   a ``FrequencyController`` backend and metering each phase against its

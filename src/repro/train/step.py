@@ -8,16 +8,17 @@ overlap the DP gradient all-reduce of microbatch *k* with the compute of
 *k+1* on real hardware (collective/compute overlap).
 
 For kernel-level DVFS the step is segmented into the three train phases of
-:data:`~repro.core.phase_plan.TRAIN_PHASES` — ``fwd`` (embedding, forward
+:data:`~repro.core.workload.TRAIN_PHASES` — ``fwd`` (embedding, forward
 layers, loss head), ``bwd`` (backward pass), ``opt`` (the AdamW update
 built here) — matching the kernel ``phase`` tags the
 :class:`~repro.core.workload.WorkloadBuilder` emits for the same step.
-:func:`~repro.core.phase_plan.plan_train_bundle` plans one clock schedule
-per phase and the :class:`~repro.runtime.dvfs_exec.TrainPhaseExecutor`
-replays them around each call of this function; the step's optimized HLO
+:func:`~repro.dvfs.phase_plan.plan_train_phases` plans one
+:class:`~repro.dvfs.DvfsPlan` segment per phase and the
+:class:`~repro.dvfs.TrainGovernorExecutor` replays them around each call
+of this function; the step's optimized HLO
 (``jax.jit(train_step).lower(...).compile().as_text()``) can be fed back
 to the planner for analytic-vs-compiled calibration
-(:func:`~repro.core.phase_plan.calibrate_workload_against_hlo`).
+(:func:`~repro.core.workload.calibrate_workload_against_hlo`).
 """
 from __future__ import annotations
 

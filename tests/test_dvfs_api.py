@@ -1,7 +1,7 @@
 """The unified DVFS governor API: plan IR round-trip + versioning,
-governor/controller registries, legacy-bundle conversion parity, executor
-adapters vs the legacy shims, and the OnlineGovernor drift -> re-plan ->
-recovery loop on a synthetic bucket-mix shift."""
+governor/controller registries, the phase planners, executor adapters,
+and the OnlineGovernor drift -> re-plan -> recovery loop on a synthetic
+bucket-mix shift."""
 import copy
 import json
 
@@ -9,35 +9,36 @@ import pytest
 
 from repro.configs import REGISTRY, get_config, get_shape
 from repro.configs.base import ShapeConfig
-from repro.core import (Campaign, PhasePlanBundle, TrainPlanBundle,
-                        WastePolicy, WorkloadBuilder, compile_phase,
-                        decode_slot_buckets, get_chip, plan_phase_bundle,
-                        plan_train_bundle)
+from repro.core import (Campaign, WastePolicy, WorkloadBuilder,
+                        decode_slot_buckets, get_chip, pass_level_plan)
 from repro.core.freq import AUTO, ClockPair
 from repro.dvfs import (SCHEMA_VERSION, DvfsPlan, DvfsSession,
-                        OnlineGovernor, PlanSegment, RateLimitedController,
+                        OnlineGovernor, RateLimitedController,
                         ServeGovernorExecutor, StaticPlanGovernor,
-                        TrainGovernorExecutor, controller, governor,
-                        plan_decode_joint, validate_plan_dict)
+                        TrainGovernorExecutor, compile_phase, controller,
+                        governor, plan_decode_joint, plan_serve_phases,
+                        plan_train_phases, validate_plan_dict)
 
 CHIP = get_chip("tpu-v5e")
 TAU = 0.006
 
 
-@pytest.fixture(scope="module")
-def serve_bundle():
-    cfg = REGISTRY["llama3.2-1b"]
-    pre = ShapeConfig(name="p", seq_len=256, global_batch=1,
-                      kind="prefill")
-    dec = ShapeConfig(name="d", seq_len=256, global_batch=4, kind="decode")
-    return plan_phase_bundle(cfg, CHIP, n_slots=4, prefill_shape=pre,
-                             decode_shape=dec, policy=WastePolicy(TAU),
-                             n_reps=3)
+SERVE_PRE = ShapeConfig(name="p", seq_len=256, global_batch=1,
+                        kind="prefill")
+SERVE_DEC = ShapeConfig(name="d", seq_len=256, global_batch=4,
+                        kind="decode")
 
 
 @pytest.fixture(scope="module")
-def train_bundle():
-    return plan_train_bundle(get_config("gpt3-xl"), CHIP,
+def serve_plan():
+    return plan_serve_phases(REGISTRY["llama3.2-1b"], CHIP, n_slots=4,
+                             prefill_shape=SERVE_PRE, decode_shape=SERVE_DEC,
+                             policy=WastePolicy(TAU), n_reps=3)
+
+
+@pytest.fixture(scope="module")
+def train_plan():
+    return plan_train_phases(get_config("gpt3-xl"), CHIP,
                              shape=get_shape("paper_gpt3xl"),
                              policy=WastePolicy(TAU), n_reps=3)
 
@@ -75,8 +76,8 @@ def test_controller_registry():
 # Plan IR: JSON round-trip + versioning + validation
 # ---------------------------------------------------------------------------
 
-def test_plan_json_roundtrip(serve_bundle):
-    plan = DvfsPlan.from_phase_bundle(serve_bundle)
+def test_plan_json_roundtrip(serve_plan):
+    plan = serve_plan
     plan2 = DvfsPlan.from_json(plan.to_json())
     assert plan2.schema_version == SCHEMA_VERSION
     assert plan2.kind == "serve"
@@ -90,16 +91,16 @@ def test_plan_json_roundtrip(serve_bundle):
         assert a.kernels == b.kernels
 
 
-def test_plan_rejects_future_schema(serve_bundle):
-    d = DvfsPlan.from_phase_bundle(serve_bundle).to_dict()
+def test_plan_rejects_future_schema(serve_plan):
+    d = serve_plan.to_dict()
     d["schema_version"] = SCHEMA_VERSION + 1
     with pytest.raises(ValueError, match="schema"):
         DvfsPlan.from_dict(d)
     assert any("newer" in e for e in validate_plan_dict(d))
 
 
-def test_validate_plan_dict(serve_bundle):
-    good = DvfsPlan.from_phase_bundle(serve_bundle).to_dict()
+def test_validate_plan_dict(serve_plan):
+    good = serve_plan.to_dict()
     assert validate_plan_dict(good) == []
     bad = copy.deepcopy(good)
     bad["kind"] = "snack"
@@ -111,92 +112,66 @@ def test_validate_plan_dict(serve_bundle):
     assert any("scope" in e for e in errs)
 
 
-def test_ir_tags_and_bucket_lookup(serve_bundle):
-    plan = DvfsPlan.from_phase_bundle(serve_bundle)
+def test_ir_tags_and_bucket_lookup(serve_plan):
+    plan = serve_plan
     assert plan.segment("prefill").scope == "serve-prefill"
     decode = [s for s in plan.segments if s.scope == "serve-decode"]
     assert [s.bucket for s in decode] == decode_slot_buckets(4)
-    assert plan.decode_bucket(3) == serve_bundle.decode_bucket(3)
-    assert plan.decode_segment(3).name \
-        == f"decode@{serve_bundle.decode_bucket(3)}"
+    assert plan.decode_bucket(3) == 4 and plan.decode_bucket(99) == 4
+    assert plan.decode_segment(3).name == "decode@4"
 
 
-# ---------------------------------------------------------------------------
-# Legacy-bundle <-> IR conversion parity (lossless)
-# ---------------------------------------------------------------------------
-
-def test_serve_bundle_conversion_parity(serve_bundle, tmp_path):
-    ir = DvfsPlan.from_phase_bundle(serve_bundle)
-    back = ir.to_phase_bundle()
-    for name, p in serve_bundle.phases().items():
-        q = back.phases()[name]
-        assert q.energy_j == p.energy_j
-        assert q.time_s == p.time_s
-        assert q.schedule.to_json() == p.schedule.to_json()
-    # the bundle's own save/load now routes through the IR wire format
-    path = str(tmp_path / "b.json")
-    serve_bundle.save(path)
-    with open(path) as f:
-        assert json.load(f)["schema_version"] == SCHEMA_VERSION
-    b2 = PhasePlanBundle.load(path)
-    assert b2.summary() == serve_bundle.summary()
-
-
-def test_train_bundle_conversion_parity(train_bundle, tmp_path):
-    ir = DvfsPlan.from_train_bundle(train_bundle)
-    assert ir.kind == "train"
-    assert ir.time_s == train_bundle.step_time_s
-    assert ir.energy_j == train_bundle.step_energy_j
-    back = ir.to_train_bundle()
-    assert back.to_json() == train_bundle.to_json()
-    path = str(tmp_path / "t.json")
-    train_bundle.save(path)
-    assert TrainPlanBundle.load(path).summary() == train_bundle.summary()
-
-
-def test_legacy_wire_format_still_loads(train_bundle):
-    """Pre-IR artifacts (no schema_version/segments keys) keep loading."""
+def test_from_json_rejects_a_pre_ir_bundle(train_plan):
+    """A document without ``segments`` (the retired bundle layout, or any
+    other non-plan) is refused with a ValueError, not a KeyError."""
     legacy = json.dumps({
-        "chip": train_bundle.chip_name,
-        "meta": train_bundle.meta,
-        "phases": {n: p.to_dict() for n, p in train_bundle.phases.items()},
+        "chip": train_plan.chip_name,
+        "meta": train_plan.meta,
+        "phases": {s.name: s.to_dict() for s in train_plan.segments},
     })
-    b = TrainPlanBundle.from_json(legacy)
-    assert b.summary() == train_bundle.summary()
+    with pytest.raises(ValueError, match="segments"):
+        DvfsPlan.from_json(legacy)
+    with pytest.raises(ValueError, match="segments"):
+        DvfsPlan.from_json("[]")
 
 
 # ---------------------------------------------------------------------------
-# Executor adapters: new vs legacy shim parity, deprecation, controllers
+# Phase planners
 # ---------------------------------------------------------------------------
 
-def test_train_executor_matches_legacy_shim(train_bundle):
-    from repro.runtime import TrainPhaseExecutor
-    with pytest.warns(DeprecationWarning, match="dvfs"):
-        old = TrainPhaseExecutor(train_bundle, CHIP)
-    new = TrainGovernorExecutor.from_bundle(train_bundle, CHIP)
-    for s in range(4):
-        assert old.on_step(s) == new.on_step(s)
-    old.finish(), new.finish()
-    assert old.summary() == new.summary()
-    # checkpoint books round-trip identically
-    resumed = TrainGovernorExecutor.from_bundle(train_bundle, CHIP)
-    resumed.load_state_dict(new.state_dict())
-    assert resumed.summary()["totals"] == new.summary()["totals"]
+@pytest.mark.parametrize("planner,granularity",
+                         [(None, "kernel"), (pass_level_plan, "pass")])
+def test_plan_serve_phases_segments(serve_plan, planner, granularity):
+    """Prefill first, then one serve-decode segment per slot bucket in
+    ascending order; granularity follows the planner."""
+    plan = serve_plan if planner is None else plan_serve_phases(
+        REGISTRY["llama3.2-1b"], CHIP, n_slots=4, prefill_shape=SERVE_PRE,
+        decode_shape=SERVE_DEC, policy=WastePolicy(TAU), n_reps=2,
+        planner=planner)
+    assert plan.kind == "serve"
+    assert [s.scope for s in plan.segments] \
+        == ["serve-prefill"] + ["serve-decode"] * 3
+    assert [s.bucket for s in plan.segments[1:]] == decode_slot_buckets(4)
+    assert plan.segment_names() == ["prefill", "decode@1", "decode@2",
+                                    "decode@4"]
+    assert {s.granularity for s in plan.segments} == {granularity}
 
 
-def test_executor_state_dict_survives_replan_carry(train_bundle):
+# ---------------------------------------------------------------------------
+# Executor adapters and controllers
+# ---------------------------------------------------------------------------
+
+def test_executor_state_dict_survives_replan_carry(train_plan):
     """Books flushed into the carry by a mid-run plan adoption must
     survive checkpoint-restart, not just the current-revision counts."""
-    gov = StaticPlanGovernor(DvfsPlan.from_train_bundle(train_bundle))
+    gov = StaticPlanGovernor(train_plan)
     ex = TrainGovernorExecutor(gov, CHIP)
     for s in range(3):
         ex.on_step(s)
-    gov.adopt(DvfsPlan.from_train_bundle(train_bundle), reason="swap")
+    gov.adopt(DvfsPlan.from_json(train_plan.to_json()), reason="swap")
     for s in range(3, 5):
         ex.on_step(s)                  # flushes pre-adopt books to carry
-    resumed = TrainGovernorExecutor(
-        StaticPlanGovernor(DvfsPlan.from_train_bundle(train_bundle)),
-        CHIP)
+    resumed = TrainGovernorExecutor(StaticPlanGovernor(train_plan), CHIP)
     resumed.load_state_dict(ex.state_dict())
     a, b = ex.summary()["totals"], resumed.summary()["totals"]
     assert a["steps"] == b["steps"] == 15        # 5 steps x 3 phases
@@ -204,21 +179,8 @@ def test_executor_state_dict_survives_replan_carry(train_bundle):
     assert abs(a["time_s"] - b["time_s"]) < 1e-9
 
 
-def test_serve_executor_matches_legacy_shim(serve_bundle):
-    from repro.runtime import PhaseExecutor
-    with pytest.warns(DeprecationWarning, match="dvfs"):
-        old = PhaseExecutor(serve_bundle, CHIP)
-    new = ServeGovernorExecutor.from_bundle(serve_bundle, CHIP)
-    for ex in (old, new):
-        ex.on_prefill()
-        for n in (1, 2, 3, 4, 4, 1):
-            ex.on_decode(n)
-        ex.finish()
-    assert old.summary() == new.summary()
-
-
-def test_executor_rejects_wrong_chip(train_bundle):
-    gov = StaticPlanGovernor(DvfsPlan.from_train_bundle(train_bundle))
+def test_executor_rejects_wrong_chip(train_plan):
+    gov = StaticPlanGovernor(train_plan)
     with pytest.raises(ValueError, match="planned for"):
         TrainGovernorExecutor(gov, get_chip("rtx3080ti"))
 
@@ -244,10 +206,10 @@ def test_rate_limited_controller_quantizes_and_throttles():
     assert ctl.current == ClockPair(AUTO, AUTO)
 
 
-def test_rate_limited_executor_realizes_fewer_switches(train_bundle):
-    free = TrainGovernorExecutor.from_bundle(train_bundle, CHIP)
-    lim = TrainGovernorExecutor.from_bundle(
-        train_bundle, CHIP,
+def test_rate_limited_executor_realizes_fewer_switches(train_plan):
+    free = TrainGovernorExecutor(StaticPlanGovernor(train_plan), CHIP)
+    lim = TrainGovernorExecutor(
+        StaticPlanGovernor(train_plan), CHIP,
         controller=RateLimitedController(CHIP, min_interval_s=1e-2))
     for s in range(3):
         free.on_step(s), lim.on_step(s)
@@ -261,7 +223,13 @@ def test_rate_limited_executor_realizes_fewer_switches(train_bundle):
 # DvfsSession facade
 # ---------------------------------------------------------------------------
 
-def test_session_train_reproduces_legacy_pipeline(train_bundle):
+def _assert_same_segments(a: DvfsPlan, b: DvfsPlan) -> None:
+    assert a.kind == b.kind and a.chip_name == b.chip_name
+    assert [s.to_dict() for s in a.segments] \
+        == [s.to_dict() for s in b.segments]
+
+
+def test_session_train_equals_plan_train_phases(train_plan):
     with DvfsSession(chip=CHIP, tau=TAU, n_reps=3) as sess:
         plan = sess.plan_train(get_config("gpt3-xl"),
                                shape=get_shape("paper_gpt3xl"))
@@ -269,13 +237,28 @@ def test_session_train_reproduces_legacy_pipeline(train_bundle):
         for s in range(3):
             ex.on_step(s)
         report = sess.report()
-    # same campaign seed + planner => bit-identical schedules
-    for ph, p in train_bundle.phases.items():
-        assert plan.segment(ph).schedule.to_json() == p.schedule.to_json()
-    assert report["governor"] == "kernel-static"
+    # same campaign seed + planner => identical segments; the session
+    # only stamps the governor's name into the meta
+    _assert_same_segments(plan, train_plan)
+    assert {k: v for k, v in plan.meta.items() if k != "governor"} \
+        == train_plan.meta
+    assert report["governor"] == plan.meta["governor"] == "kernel-static"
     assert report["executed"][0]["totals"]["steps"] == 9
     assert report["plan"]["phases"].keys() \
-        == train_bundle.summary()["phases"].keys()
+        == train_plan.summary()["phases"].keys()
+
+
+def test_session_serve_equals_plan_serve_phases(serve_plan):
+    with DvfsSession(chip=CHIP, tau=TAU, n_reps=3) as sess:
+        plan = sess.plan_serve(REGISTRY["llama3.2-1b"], n_slots=4,
+                               prefill_shape=SERVE_PRE,
+                               decode_shape=SERVE_DEC)
+    _assert_same_segments(plan, serve_plan)
+    added = {"governor", "decode_mix", "n_slots"}
+    assert {k: v for k, v in plan.meta.items() if k not in added} \
+        == {k: v for k, v in serve_plan.meta.items() if k not in added}
+    assert plan.meta["governor"] == "kernel-static"
+    assert plan.meta["decode_mix"] == {1: 1 / 3, 2: 1 / 3, 4: 1 / 3}
 
 
 def test_session_governor_kwargs_and_exclusive_policy():
@@ -285,7 +268,7 @@ def test_session_governor_kwargs_and_exclusive_policy():
     assert sess.governor.aggregation == "local"
 
 
-def test_static_local_aggregation_reaches_phase_path(train_bundle):
+def test_static_local_aggregation_reaches_phase_path(train_plan):
     """aggregation='local' must shape plan_train/plan_serve, not just
     solve(): the compiled phases carry the local per-kernel planner."""
     with DvfsSession(chip=CHIP, tau=TAU, n_reps=3,
@@ -295,7 +278,7 @@ def test_static_local_aggregation_reaches_phase_path(train_bundle):
     for seg in plan.segments:
         assert seg.schedule.meta["plan"] == "kernel-local"
     # and the default (global) still compiles switch-aware coalesced
-    assert train_bundle.phases["fwd"].schedule.meta["plan"] \
+    assert train_plan.segment("fwd").schedule.meta["plan"] \
         == "coalesced-global"
 
 
@@ -356,9 +339,8 @@ def decode_tables():
 
 def _serve_plan(decode_tables, mix, policy):
     segs = plan_decode_joint(decode_tables, mix, CHIP, policy)
-    prefill = PlanSegment.from_phase_plan(
-        compile_phase(decode_tables[1], "prefill", CHIP, policy),
-        scope="serve-prefill")
+    prefill = compile_phase(decode_tables[1], "prefill", CHIP, policy,
+                            scope="serve-prefill")
     return DvfsPlan(chip_name=CHIP.name, kind="serve",
                     segments=[prefill] + segs,
                     meta={"decode_mix": dict(mix)})
@@ -450,17 +432,16 @@ def test_online_governor_perf_drift_channel(decode_tables):
 
 def test_renamed_prefill_round_trips_and_executes(decode_tables):
     """Prefill segments are found by scope, not by the name 'prefill' —
-    a bundle with a custom prefill name must save/load and execute."""
+    a plan with a custom prefill name must save/load and execute."""
     policy = WastePolicy(0.01)
-    bundle = PhasePlanBundle(
-        chip_name=CHIP.name,
-        prefill=compile_phase(decode_tables[1], "prefill_ctx", CHIP,
-                              policy),
-        decode={1: compile_phase(decode_tables[1], "decode@1", CHIP,
-                                 policy)})
-    b2 = PhasePlanBundle.from_json(bundle.to_json())
-    assert b2.prefill.name == "prefill_ctx"
-    ex = ServeGovernorExecutor.from_bundle(bundle, CHIP)
+    plan = DvfsPlan(chip_name=CHIP.name, kind="serve", segments=[
+        compile_phase(decode_tables[1], "prefill_ctx", CHIP, policy,
+                      scope="serve-prefill"),
+        compile_phase(decode_tables[1], "decode@1", CHIP, policy,
+                      scope="serve-decode", bucket=1)])
+    p2 = DvfsPlan.from_json(plan.to_json())
+    assert p2.prefill_segment().name == "prefill_ctx"
+    ex = ServeGovernorExecutor(StaticPlanGovernor(p2), CHIP)
     ex.on_prefill()
     ex.finish()
     assert ex.summary()["phases"]["prefill_ctx"]["steps"] == 1

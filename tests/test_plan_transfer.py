@@ -1,14 +1,15 @@
 """DP/TP plan transfer: per-kernel choice invariance under mesh
 rescaling, energy parity vs per-mesh replanning, and FT-restart mid-plan
 resume of the executed plan."""
-import numpy as np
 import pytest
 
 from repro.configs import get_config, get_shape
-from repro.core import WastePolicy, get_chip, plan_train_bundle
+from repro.core import WastePolicy, get_chip
 from repro.core.freq import AUTO
+from repro.dvfs import (DvfsPlan, StaticPlanGovernor, TrainGovernorExecutor,
+                        plan_train_phases)
 from repro.launch.mesh import MeshSpec
-from repro.parallel import compare_transfer, transfer_train_bundle
+from repro.parallel import compare_transfer, transfer_train_plan
 
 TAU = 0.006
 
@@ -18,7 +19,7 @@ def setup():
     cfg = get_config("gpt3-xl")
     shape = get_shape("paper_gpt3xl")
     chip = get_chip("tpu-v5e")
-    src = plan_train_bundle(cfg, chip, shape=shape,
+    src = plan_train_phases(cfg, chip, shape=shape,
                             policy=WastePolicy(TAU), n_reps=3)
     return cfg, shape, chip, src
 
@@ -50,43 +51,40 @@ def test_dp_choice_invariance(setup):
     from repro.parallel.plan_transfer import NAME_PREF_LOG_AI
     cfg, shape, chip, src = setup
     for dp in (2, 4):
-        xfer = transfer_train_bundle(src, cfg, chip, shape,
-                                     MeshSpec(dp=dp), n_reps=3)
-        for ph in src.phase_names():
-            meta = xfer.phases[ph].schedule.meta
-            assert meta["n_unmatched"] == 0
-            src_ai = {k.name: k.arithmetic_intensity
-                      for k in src.phases[ph].kernels}
-            src_pairs = dict(zip(
-                (k.name for k in src.phases[ph].kernels),
-                src.phases[ph].kernel_clock_pairs()))
-            x_pairs = dict(zip(
-                (k.name for k in xfer.phases[ph].kernels),
-                xfer.phases[ph].kernel_clock_pairs()))
+        xfer = transfer_train_plan(src, cfg, chip, shape,
+                                   MeshSpec(dp=dp), n_reps=3)
+        for ph in src.segment_names():
+            s_seg, x_seg = src.segment(ph), xfer.segment(ph)
+            assert x_seg.schedule.meta["n_unmatched"] == 0
+            src_ai = {k.name: k.arithmetic_intensity for k in s_seg.kernels}
+            src_pairs = dict(zip((k.name for k in s_seg.kernels),
+                                 s_seg.kernel_clock_pairs()))
+            x_pairs = dict(zip((k.name for k in x_seg.kernels),
+                               x_seg.kernel_clock_pairs()))
             n_stable = 0
-            for k in xfer.phases[ph].kernels:
+            for k in x_seg.kernels:
                 shift = abs(math.log(max(k.arithmetic_intensity, 1e-9))
                             - math.log(max(src_ai[k.name], 1e-9)))
                 if shift <= NAME_PREF_LOG_AI:
                     assert x_pairs[k.name] == src_pairs[k.name], \
                         (dp, ph, k.name)
                     n_stable += 1
-            assert n_stable >= len(xfer.phases[ph].kernels) - 1
+            assert n_stable >= len(x_seg.kernels) - 1
     # at dp=2 nothing moves: the transfer is a verbatim replay
-    xfer2 = transfer_train_bundle(src, cfg, chip, shape, MeshSpec(dp=2),
-                                  n_reps=3)
-    assert all(xfer2.phases[ph].schedule.meta["n_remapped"] == 0
-               for ph in xfer2.phase_names())
+    xfer2 = transfer_train_plan(src, cfg, chip, shape, MeshSpec(dp=2),
+                                n_reps=3)
+    assert all(seg.schedule.meta["n_remapped"] == 0
+               for seg in xfer2.segments)
 
 
 def test_tp_transfer_remaps_along_roofline(setup):
     """TP sharding cuts GEMM arithmetic intensity ~tp-fold; the transfer
     must remap at least some kernels instead of replaying stale clocks."""
     cfg, shape, chip, src = setup
-    xfer = transfer_train_bundle(src, cfg, chip, shape, MeshSpec(tp=4),
-                                 n_reps=3)
-    remapped = sum(xfer.phases[ph].schedule.meta["n_remapped"]
-                   for ph in xfer.phase_names())
+    xfer = transfer_train_plan(src, cfg, chip, shape, MeshSpec(tp=4),
+                               n_reps=3)
+    remapped = sum(seg.schedule.meta["n_remapped"]
+                   for seg in xfer.segments)
     assert remapped > 0
 
 
@@ -109,14 +107,13 @@ def test_unmatched_collectives_fall_back_to_auto(setup):
     """Kernels that exist only in the sharded workload (TP collectives)
     were never measured by the source campaign -> auto clocks."""
     cfg, shape, chip, src = setup
-    xfer = transfer_train_bundle(src, cfg, chip, shape, MeshSpec(tp=2),
-                                 n_reps=2, include_comm=True)
+    xfer = transfer_train_plan(src, cfg, chip, shape, MeshSpec(tp=2),
+                               n_reps=2, include_comm=True)
     n_unmatched = 0
-    for ph in xfer.phase_names():
-        plan = xfer.phases[ph]
-        n_unmatched += plan.schedule.meta["n_unmatched"]
-        pairs = dict(zip((k.name for k in plan.kernels),
-                         plan.kernel_clock_pairs()))
+    for seg in xfer.segments:
+        n_unmatched += seg.schedule.meta["n_unmatched"]
+        pairs = dict(zip((k.name for k in seg.kernels),
+                         seg.kernel_clock_pairs()))
         for name, pair in pairs.items():
             if "AllReduce" in name:
                 assert pair == (AUTO, AUTO)
@@ -124,13 +121,12 @@ def test_unmatched_collectives_fall_back_to_auto(setup):
 
 
 def test_transferred_bundle_executes(setup):
-    """A transferred bundle is a first-class TrainPlanBundle: it replays
+    """A transferred plan is a first-class train DvfsPlan: it replays
     through the executor with per-shard accounting."""
-    from repro.runtime import TrainPhaseExecutor
     cfg, shape, chip, src = setup
-    xfer = transfer_train_bundle(src, cfg, chip, shape, MeshSpec(dp=2),
-                                 n_reps=3)
-    ex = TrainPhaseExecutor(xfer, chip)
+    xfer = transfer_train_plan(src, cfg, chip, shape, MeshSpec(dp=2),
+                               n_reps=3)
+    ex = TrainGovernorExecutor(StaticPlanGovernor(xfer), chip)
     for s in range(3):
         ex.on_step(s)
     tot = ex.summary()["totals"]
@@ -149,15 +145,15 @@ def test_ft_restart_mid_plan_resume(tmp_path):
     from repro.ckpt import CheckpointManager
     from repro.data import DataPipeline
     from repro.models import build_model
-    from repro.runtime import FailureInjector, TrainPhaseExecutor
+    from repro.runtime import FailureInjector
     from repro.train import OptimizerConfig, make_train_step
     from repro.train.loop import Trainer, TrainerConfig
 
     chip = get_chip("tpu-v5e")
     full = get_config("gpt3-xl")
     shape = get_shape("paper_gpt3xl")
-    bundle = plan_train_bundle(full, chip, shape=shape,
-                               policy=WastePolicy(TAU), n_reps=2)
+    plan = plan_train_phases(full, chip, shape=shape,
+                             policy=WastePolicy(TAU), n_reps=2)
 
     def run(workdir, fail_at):
         cfg = smoke_config(REGISTRY["gpt3-xl"])
@@ -167,7 +163,7 @@ def test_ft_restart_mid_plan_resume(tmp_path):
                                                       decay_steps=100))
         pipeline = DataPipeline(vocab_size=cfg.vocab_size,
                                 batch_per_host=4, seq_len=32)
-        ex = TrainPhaseExecutor(bundle, chip)
+        ex = TrainGovernorExecutor(StaticPlanGovernor(plan), chip)
         trainer = Trainer(model, step, pipeline,
                           CheckpointManager(str(workdir), keep=2),
                           TrainerConfig(total_steps=12, ckpt_every=4,
@@ -200,17 +196,48 @@ def test_transfer_chip_mismatch_raises(setup):
     refuse it up front, like the executors do."""
     cfg, shape, chip, src = setup
     with pytest.raises(ValueError, match="planned for"):
-        transfer_train_bundle(src, cfg, get_chip("rtx3080ti"), shape,
-                              MeshSpec(dp=2), n_reps=1)
+        transfer_train_plan(src, cfg, get_chip("rtx3080ti"), shape,
+                            MeshSpec(dp=2), n_reps=1)
 
 
 def test_transfer_meta_provenance(setup):
     cfg, shape, chip, src = setup
-    xfer = transfer_train_bundle(src, cfg, chip, shape,
-                                 MeshSpec(dp=2, tp=2), n_reps=2)
+    xfer = transfer_train_plan(src, cfg, chip, shape,
+                               MeshSpec(dp=2, tp=2), n_reps=2)
     assert xfer.meta["transferred"] is True
     assert xfer.meta["mesh"] == "dp2_tp2"
     assert xfer.meta["dp"] == 2 and xfer.meta["tp"] == 2
-    for ph in xfer.phase_names():
-        assert xfer.phases[ph].schedule.meta["transferred_from"]["model"] \
-            == "gpt3-xl"
+    for seg in xfer.segments:
+        assert seg.schedule.meta["transferred_from"]["model"] == "gpt3-xl"
+
+
+def test_transfer_rejects_a_serve_plan(setup):
+    """The train transfer takes train plans only, as the serve transfer
+    takes serve plans only."""
+    cfg, shape, chip, src = setup
+    serve = DvfsPlan(chip_name=src.chip_name, kind="serve",
+                     segments=list(src.segments), meta=dict(src.meta))
+    with pytest.raises(ValueError, match="not a train plan"):
+        transfer_train_plan(serve, cfg, chip, shape, MeshSpec(dp=2),
+                            n_reps=1)
+
+
+def test_transferred_plan_roundtrips_and_executes(setup, tmp_path):
+    """transfer_train_plan emits a train DvfsPlan with the train scopes
+    in step order; it survives save/load and replays through the
+    governor executor."""
+    cfg, shape, chip, src = setup
+    xfer = transfer_train_plan(src, cfg, chip, shape, MeshSpec(dp=2),
+                               n_reps=2)
+    assert xfer.kind == "train"
+    assert [s.scope for s in xfer.segments] \
+        == ["train-fwd", "train-bwd", "train-opt"]
+    path = str(tmp_path / "xfer.json")
+    xfer.save(path)
+    back = DvfsPlan.load(path)
+    assert back.summary() == xfer.summary()
+    ex = TrainGovernorExecutor(StaticPlanGovernor(back), chip)
+    for s in range(2):
+        ex.on_step(s)
+    ex.finish()
+    assert ex.summary()["totals"]["steps"] == 2 * len(xfer.segments)

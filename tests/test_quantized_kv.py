@@ -392,25 +392,25 @@ def test_quantized_replan_lands_deeper_serve_energy_cut():
     strictly deeper — by several points at the KV-heavy top bucket."""
     from repro.configs.base import ShapeConfig
     from repro.core.objectives import WastePolicy
-    from repro.core.phase_plan import plan_phase_bundle
     from repro.core.power_model import get_chip
+    from repro.dvfs import plan_serve_phases
     cfg = REGISTRY["llama3.2-1b"]
     chip = get_chip("tpu-v5e")
     pre = ShapeConfig(name="p", seq_len=256, global_batch=1,
                       kind="prefill")
     dec = ShapeConfig(name="d", seq_len=4096, global_batch=8,
                       kind="decode")
-    phases = {}
+    plans = {}
     for kvd in (None, "int8"):
-        bundle = plan_phase_bundle(cfg, chip, n_slots=8, prefill_shape=pre,
-                                   decode_shape=dec,
-                                   policy=WastePolicy(0.005), n_reps=2,
-                                   kv_dtype=kvd)
-        assert bundle.meta["kv_dtype"] == (kvd or "none")
-        phases[kvd or "bf16"] = bundle.phases()
+        plan = plan_serve_phases(cfg, chip, n_slots=8, prefill_shape=pre,
+                                 decode_shape=dec,
+                                 policy=WastePolicy(0.005), n_reps=2,
+                                 kv_dtype=kvd)
+        assert plan.meta["kv_dtype"] == (kvd or "none")
+        plans[kvd or "bf16"] = plan
     for bucket in (1, 2, 4, 8):
-        m0 = phases["bf16"][f"decode@{bucket}"].schedule.meta
-        m1 = phases["int8"][f"decode@{bucket}"].schedule.meta
+        m0 = plans["bf16"].segment(f"decode@{bucket}").schedule.meta
+        m1 = plans["int8"].segment(f"decode@{bucket}").schedule.meta
         # the planner sees the shifted roofline ...
         assert m1["base_time_s"] < m0["base_time_s"], bucket
         assert m1["base_energy_j"] < m0["base_energy_j"], bucket
@@ -421,21 +421,21 @@ def test_quantized_replan_lands_deeper_serve_energy_cut():
         assert abs(m1["time_pct"]) <= 0.5 + 1e-6          # tau respected
     # top bucket (most HBM-bound decode): the cut against the shared
     # bf16 baseline deepens by >5 points (quantization + DVFS compound)
-    m0 = phases["bf16"]["decode@8"].schedule.meta
-    m1 = phases["int8"]["decode@8"].schedule.meta
+    m0 = plans["bf16"].segment("decode@8").schedule.meta
+    m1 = plans["int8"].segment("decode@8").schedule.meta
     gov0 = m0["base_energy_j"] * (1 + m0["energy_pct"] / 100)
     gov1 = m1["base_energy_j"] * (1 + m1["energy_pct"] / 100)
     cut0 = 1 - gov0 / m0["base_energy_j"]
     cut1 = 1 - gov1 / m0["base_energy_j"]
     assert cut1 > cut0 + 0.05
     # prefill is untouched by kv_dtype (no decode cache-read stream)
-    p0 = phases["bf16"]["prefill"].schedule.meta
-    p1 = phases["int8"]["prefill"].schedule.meta
+    p0 = plans["bf16"].segment("prefill").schedule.meta
+    p1 = plans["int8"].segment("prefill").schedule.meta
     assert p0["base_energy_j"] == p1["base_energy_j"]
 
 
 def test_session_plan_serve_threads_kv_dtype():
-    """DvfsSession.plan_serve(kv_dtype=...) stamps the bundle meta and
+    """DvfsSession.plan_serve(kv_dtype=...) stamps the plan meta and
     plans against the quantized workload model."""
     from repro.configs.base import ShapeConfig
     from repro.dvfs import DvfsSession

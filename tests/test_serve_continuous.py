@@ -1,5 +1,5 @@
 """Continuous-batching engine: parity vs the wave baseline, scheduler
-lifecycle, phase-plan bundles, and executed-energy replay accounting."""
+lifecycle, serve phase plans, and executed-energy replay accounting."""
 import dataclasses
 
 import jax
@@ -10,11 +10,12 @@ import pytest
 from repro.configs import REGISTRY, smoke_config
 from repro.configs.base import ShapeConfig
 from repro.core import (Campaign, WastePolicy, get_chip, global_plan,
-                        plan_phase_bundle, schedule_from_plan,
-                        decode_slot_buckets, PhasePlanBundle)
+                        schedule_from_plan, decode_slot_buckets)
 from repro.core.power_model import KernelSpec
+from repro.dvfs import (DvfsPlan, ServeGovernorExecutor, StaticPlanGovernor,
+                        plan_serve_phases)
 from repro.models import build_model
-from repro.runtime import EnergyMeter, PhaseExecutor, SimulatedController
+from repro.runtime import EnergyMeter, SimulatedController
 from repro.serve import Request, Scheduler, ServeEngine, WaveEngine
 
 
@@ -109,7 +110,7 @@ def test_decode_slot_buckets():
 
 
 # ---------------------------------------------------------------------------
-# phase-plan bundle + replay accounting
+# serve phase plan + replay accounting
 # ---------------------------------------------------------------------------
 
 CHIP = get_chip("tpu-v5e")
@@ -118,56 +119,58 @@ DEC = ShapeConfig(name="dec", seq_len=512, global_batch=4, kind="decode")
 
 
 @pytest.fixture(scope="module")
-def bundle():
-    return plan_phase_bundle(REGISTRY["llama3.2-1b"], CHIP, n_slots=4,
+def serve_plan():
+    return plan_serve_phases(REGISTRY["llama3.2-1b"], CHIP, n_slots=4,
                              prefill_shape=PRE, decode_shape=DEC,
                              policy=WastePolicy(0.005), n_reps=10)
 
 
-def test_bundle_json_roundtrip(bundle, tmp_path):
-    p = tmp_path / "bundle.json"
-    bundle.save(str(p))
-    b2 = PhasePlanBundle.load(str(p))
-    assert b2.chip_name == bundle.chip_name
-    assert b2.buckets == bundle.buckets == [1, 2, 4]
-    assert b2.decode_bucket(3) == 4 and b2.decode_bucket(99) == 4
-    for name, plan in bundle.phases().items():
-        p2 = b2.phases()[name]
-        assert [dataclasses.asdict(e) for e in p2.schedule.entries] == \
-            [dataclasses.asdict(e) for e in plan.schedule.entries]
-        assert p2.kernels == plan.kernels
+def test_bundle_json_roundtrip(serve_plan, tmp_path):
+    p = tmp_path / "plan.json"
+    serve_plan.save(str(p))
+    p2 = DvfsPlan.load(str(p))
+    assert p2.chip_name == serve_plan.chip_name
+    assert p2.decode_buckets == serve_plan.decode_buckets == [1, 2, 4]
+    assert p2.decode_bucket(3) == 4 and p2.decode_bucket(99) == 4
+    assert p2.segment_names() == serve_plan.segment_names()
+    for a, b in zip(serve_plan.segments, p2.segments):
+        assert [dataclasses.asdict(e) for e in b.schedule.entries] == \
+            [dataclasses.asdict(e) for e in a.schedule.entries]
+        assert b.kernels == a.kernels
 
 
-def test_replay_energy_matches_plan_prediction(bundle):
+def test_replay_energy_matches_plan_prediction(serve_plan):
     """The engine's EnergyMeter totals must match the plan's predicted
     energy_j within tolerance (prediction is off a noisy campaign; the
     meter integrates the noise-free chip model)."""
-    for name, plan in bundle.phases().items():
-        meter = EnergyMeter(CHIP, plan.kernels, plan.schedule)
+    for seg in serve_plan.segments:
+        meter = EnergyMeter(CHIP, seg.kernels, seg.schedule)
         n = 7
         for i in range(n):
             meter.on_step(i)
         tot = meter.totals()
-        predicted = plan.schedule.meta["energy_j"]
+        predicted = seg.schedule.meta["energy_j"]
         assert predicted > 0
         assert tot["energy_j"] / n == pytest.approx(predicted, rel=0.03), \
-            name
+            seg.name
         assert tot["time_s"] / n == pytest.approx(
-            plan.schedule.meta["time_s"], rel=0.03), name
+            seg.schedule.meta["time_s"], rel=0.03), seg.name
 
 
-def test_executed_bundle_saves_energy_within_budget(bundle, smoke_model):
+def test_executed_bundle_saves_energy_within_budget(serve_plan,
+                                                    smoke_model):
     """End-to-end replay through the engine: energy savings at <= the
     policy's time budget, per-phase switch counts surfaced."""
     model, params, cfg = smoke_model
-    ex = PhaseExecutor(bundle, CHIP, SimulatedController(CHIP))
+    ex = ServeGovernorExecutor(StaticPlanGovernor(serve_plan), CHIP,
+                               SimulatedController(CHIP))
     eng = ServeEngine(model, params, batch_slots=4, max_seq=64,
                       executor=ex)
     eng.generate(_requests(cfg, n=8))
     s = eng.energy_summary()
     tot = s["totals"]
     assert tot["energy_j"] < tot["base_energy_j"]          # saves energy
-    tau_pct = 100 * bundle.meta["tau"]
+    tau_pct = 100 * serve_plan.meta["tau"]
     assert tot["time_pct"] <= tau_pct + 0.05               # within budget
     assert "n_switches" in tot
     for row in s["phases"].values():                       # per-phase counts
