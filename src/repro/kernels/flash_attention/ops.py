@@ -10,8 +10,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .kernel import flash_attention_fwd
+
+#: Names of the training forward's two residuals that only the kernel
+#: computes, its output and log-sum-exp.  A checkpoint policy that saves
+#: them (``models/common.py:remat``) keeps the backward from running the
+#: forward kernel again; q, k and v are rebuilt from the layer's input.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -90,6 +97,8 @@ def _fa_train_fwd(q, k, v, causal, window, block_q, block_k, interpret):
                                  block_q=bq, block_k=bk, group=1,
                                  kv_len=Sk, return_lse=True,
                                  interpret=interpret)
+    o = checkpoint_name(o, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     res = (qp, kp, vp, o, lse, dims)
     out = o[:, :Sq] if pq else o
     return out.reshape(B, H, Sq, Dv).transpose(0, 2, 1, 3), res

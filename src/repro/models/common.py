@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.flash_attention import FLASH_RESIDUALS
 from ..obs.scopes import scope
 
 Params = Dict[str, Any]
@@ -68,6 +69,18 @@ class ParamBuilder:
             fan_in = shape[0] if len(shape) > 1 else max(shape[0], 1)
             scale = fan_in ** -0.5
         return (jax.random.normal(key, shape) * scale).astype(self.dtype)
+
+
+def remat(fn):
+    """``fn`` under ``jax.checkpoint``, the rule of every layer stack: the
+    backward recomputes the layer from its input but keeps the training
+    flash kernel's output and log-sum-exp (``FLASH_RESIDUALS``), so that
+    kernel runs once.  A layer without it saves nothing, as with no
+    policy."""
+    return jax.checkpoint(
+        fn, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS))
 
 
 def cast(x, dtype):

@@ -99,7 +99,7 @@ class EncDecLM:
             return x, None
 
         if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = cm.remat(body)
         x, _ = lax.scan(body, x, params["enc_layers"])
         return cm.apply_norm(params["enc_final_norm"], x, cfg.norm)
 
@@ -129,7 +129,7 @@ class EncDecLM:
             return self._dec_body(lp, x, memory), None
 
         if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = cm.remat(body)
         x, _ = lax.scan(body, x, params["dec_layers"])
         x = cm.apply_norm(params["final_norm"], x, cfg.norm)
         logits = cm.unembed(params["embed"], x)
@@ -197,7 +197,7 @@ class EncDecLM:
             return x, cache
 
         if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = cm.remat(body)
         x, cache = lax.scan(body, x, params["dec_layers"])
         last = x[:, -1:] if lens is None \
             else cm.gather_last_positions(x, lens)

@@ -169,8 +169,7 @@ class HybridLM:
                 x = x + mamba_block(lp["mamba"], h, cfg)
             return x, None
 
-        body = jax.checkpoint(group_body, prevent_cse=False) if remat \
-            else group_body
+        body = cm.remat(group_body) if remat else group_body
         x, _ = lax.scan(body, x, glayers)
         if self.tail:
             x = self._shared_fwd(shared, x, emb)
@@ -249,8 +248,7 @@ class HybridLM:
             cache["conv"] = jnp.stack(cache["conv"])
             return x, cache
 
-        body = jax.checkpoint(group_body, prevent_cse=False) if remat \
-            else group_body
+        body = cm.remat(group_body) if remat else group_body
         x, cache = lax.scan(body, x, glayers)
         cache = {"ssm": cache["ssm"].reshape((n_scan,) +
                                              cache["ssm"].shape[2:]),

@@ -323,7 +323,7 @@ class MambaLM:
             return x + mamba_block(lp["mamba"], h, cfg), None
 
         if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = cm.remat(body)
         x, _ = lax.scan(body, x, params["layers"])
         return x, {}
 
@@ -368,7 +368,7 @@ class MambaLM:
             return x + out, {"ssm": hf, "conv": tail}
 
         if remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+            body = cm.remat(body)
         x, cache = lax.scan(body, x, params["layers"])
         last = x[:, -1:] if lens is None \
             else cm.gather_last_positions(x, lens)

@@ -195,7 +195,7 @@ class DecoderLM:
             dense = functools.partial(self._layer_fwd, idx_in_group=0,
                                       q_offset=q_offset, aux_acc={})
             if remat:
-                dense = jax.checkpoint(dense, prevent_cse=False)
+                dense = cm.remat(dense)
             x, _ = dense(jax.tree.map(lambda a, i=i: a[i],
                                       params["dense_layers"]), x)
         glayers = jax.tree.map(
@@ -219,10 +219,7 @@ class DecoderLM:
                  for k in ("load_balance", "router_z", "dropped_frac")])
             return x, aux_vec
 
-        body = group_body
-        if remat:
-            body = jax.checkpoint(group_body,
-                                  prevent_cse=False)
+        body = cm.remat(group_body) if remat else group_body
         x, aux_stack = lax.scan(body, x, glayers)
         aux = {}
         if self.routed:
@@ -382,7 +379,7 @@ class DecoderLM:
             return x, new_cache
 
         if remat:
-            group_body = jax.checkpoint(group_body, prevent_cse=False)
+            group_body = cm.remat(group_body)
         x, cache = lax.scan(group_body, x, glayers)
         last = x[:, -1:] if valid_len is None \
             else cm.gather_last_positions(x, valid_len)

@@ -189,3 +189,42 @@ def test_routed_expert_layer_compiles(one_chip, monkeypatch, backward):
     # gradient, the two gradients of each input and the three weights'
     per_branch = 9 if backward else 3
     assert sorted(rows) == [12288] * per_branch + [49152] * per_branch
+
+
+@pytest.mark.parametrize("checkpoint,forwards", [("remat", 1), ("plain", 2)])
+def test_remat_mla_stack_runs_flash_forward_once(one_chip, monkeypatch,
+                                                 checkpoint, forwards):
+    """The gradient of a 2-layer latent-attention stack at Moonlight's
+    widths (2048 wide, 16 heads, q/k 192, v 128; S 1024) under the layer
+    checkpoint keeps the forward kernel's output and log-sum-exp: its
+    backward loop holds the two backward kernels and no second forward,
+    which the plain checkpoint's does."""
+    from repro.configs.base import MLAConfig
+    from repro.models import common as cm
+    monkeypatch.setattr(cm, "_attention_kernels",
+                        lambda: {"interpret": False})
+    mla = MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                    qk_rope_head_dim=64, v_head_dim=128)
+    d, H, S = 2048, 16, 1024
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    params = {"wq": sds((2, d, H, 192)), "w_kva": sds((2, d, 512 + 64)),
+              "kv_norm": {"scale": sds((2, 512))},
+              "w_kvb": sds((2, 512, H, 128 + 128)),
+              "wo": sds((2, H, 128, d))}
+    wrap = cm.remat if checkpoint == "remat" else \
+        (lambda fn: jax.checkpoint(fn, prevent_cse=False))
+
+    def layer(x, p):
+        return x + cm.mla_attention_block(p, x, n_heads=H, mla=mla,
+                                          theta=5e4, block_k=1024), None
+
+    def loss(params, x):
+        y, _ = jax.lax.scan(wrap(layer), x.astype(jnp.bfloat16), params)
+        return y.astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss), params, sds((1, S, d)))
+    calls = [m.group(1) for m in map(_KERNEL.search, text.splitlines())
+             if m and "tpu_custom_call" in m.string]
+    assert sorted(calls) == sorted(
+        ["flash_fwd"] * forwards + ["flash_bwd_dq", "flash_bwd_dkv"])
